@@ -43,8 +43,8 @@
 #                       pip install -r requirements-dev.txt
 #
 # CI (.github/workflows/ci.yml) runs `make lint`, the tier-1 suite on
-# Python 3.10 + 3.11, and `make bench-smoke` with BENCH_*.json uploaded
-# as workflow artifacts.
+# Python 3.12 with jax[cpu]==0.9.0, and `make bench-smoke` with
+# BENCH_*.json uploaded as workflow artifacts.
 
 PY        ?= python
 PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH),)

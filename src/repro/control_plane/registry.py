@@ -82,8 +82,7 @@ class ProcessShardedRegistry:
                  shard_by: str = "peer",
                  policy: Optional[RpcPolicy] = None,
                  clock: Optional[Clock] = None,
-                 transport_factory: Optional[Callable[[int], object]] = None,
-                 start_method: Optional[str] = None):
+                 transport_factory: Optional[Callable[[int], object]] = None):
         if n_shards < 1:
             raise ValueError(f"n_shards must be >= 1, got {n_shards}")
         if shard_by not in ("peer", "layer"):
@@ -98,8 +97,7 @@ class ProcessShardedRegistry:
         self.clock: Clock = clock if clock is not None else SystemClock()
         self.health = ControlPlaneHealth()
         if transport_factory is None:
-            transport_factory = lambda s: ProcWorker(  # noqa: E731
-                cfg, s, start_method=start_method)
+            transport_factory = lambda s: ProcWorker(cfg, s)  # noqa: E731
         self._factory = transport_factory
         self.channels: List[RpcChannel] = [
             RpcChannel(transport_factory(s), self.policy, self.clock,

@@ -51,6 +51,11 @@ from repro.control_plane.rpc import RpcTimeout
 # handful of in-flight commands of the original, so a small cache is ample
 DEDUP_CACHE = 512
 
+# a spawned worker re-imports the package before it can serve; it posts
+# READY once, and that start-up is waited for here, outside RPC deadlines
+READY = "ready"
+STARTUP_TIMEOUT_S = 120.0
+
 
 class ShardHost:
     """One shard's registry + command dispatch (transport-agnostic)."""
@@ -232,6 +237,7 @@ def worker_main(cfg: GTRACConfig, shard: int, cmd_q, rep_q) -> None:
     workers mid-reply), SIGKILL is the chaos path."""
     signal.signal(signal.SIGINT, signal.SIG_IGN)
     host = ShardHost(cfg, shard)
+    rep_q.put(READY)
     while True:
         req_id, op, args = cmd_q.get()
         if op == "stop":
@@ -245,12 +251,11 @@ class ProcWorker:
     """Parent-side handle for one shard worker process — the queue-backed
     ``Transport``."""
 
-    def __init__(self, cfg: GTRACConfig, shard: int,
-                 start_method: Optional[str] = None):
-        if start_method is None:
-            start_method = ("fork" if "fork" in mp.get_all_start_methods()
-                            else "spawn")
-        ctx = mp.get_context(start_method)
+    def __init__(self, cfg: GTRACConfig, shard: int):
+        # spawn, never fork: the parent is multi-threaded (JAX, and on a
+        # TPU host libtpu), and a fork of it may deadlock in the child
+        # or inherit the parent's hold on the chip
+        ctx = mp.get_context("spawn")
         self.cmd_q = ctx.Queue()
         self.rep_q = ctx.Queue()
         self.proc = ctx.Process(target=worker_main,
@@ -259,12 +264,27 @@ class ProcWorker:
                                 name=f"anchor-shard-{int(shard)}",
                                 daemon=True)
         self.proc.start()
+        self._ready = False
+
+    def _await_ready(self) -> None:
+        deadline = _time.monotonic() + STARTUP_TIMEOUT_S
+        while self.proc.is_alive() and _time.monotonic() < deadline:
+            try:
+                item = self.rep_q.get(timeout=0.1)
+            except _queue.Empty:
+                continue
+            assert item == READY, item
+            self._ready = True
+            return
+        raise RpcTimeout(f"{self.proc.name}: worker did not start")
 
     # Transport protocol
     def post(self, msg: Tuple) -> None:
         self.cmd_q.put(msg)
 
     def poll(self, timeout_s: float) -> Tuple:
+        if not self._ready:
+            self._await_ready()
         try:
             return self.rep_q.get(timeout=max(1e-4, float(timeout_s)))
         except _queue.Empty:

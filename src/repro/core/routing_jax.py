@@ -243,9 +243,12 @@ def route_batched_kbest(table: PeerTable, total_layers: int,
     numpy path — without a reverse id lookup.
     """
     tau = np.asarray(tau)
-    if tau.shape[0] == 0:
-        return (np.full((0, k_best, k_max), -1, np.int64),
-                np.full((0, k_best), float(INF), np.float32))
+    if tau.shape[0] == 0 or len(table) == 0:
+        # no requests, or no peers (e.g. every shard still unsynced): the
+        # device DPs cannot reduce over zero peers, and nothing is routable
+        R = tau.shape[0]
+        return (np.full((R, k_best, k_max), -1, np.int64),
+                np.full((R, k_best), float(INF), np.float32))
     starts, ends, costs = _device_inputs(table, total_layers, cfg, tau,
                                          planner)
     if use_kernel:

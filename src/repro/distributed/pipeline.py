@@ -86,8 +86,6 @@ def pipeline_shard_map(stage_fn: Callable, mesh: Mesh, n_microbatches: int,
     S = dict(zip(mesh.axis_names, mesh.devices.shape))[stage_axis]
 
     def pipelined(x):
-        from repro.distributed.compat import shard_map_nocheck
-
         def per_stage(x_local):
             # x_local: (M, b, ...) microbatches resident on this stage
             stage = jax.lax.axis_index(stage_axis)
@@ -122,9 +120,10 @@ def pipeline_shard_map(stage_fn: Callable, mesh: Mesh, n_microbatches: int,
             return jax.lax.psum(out, stage_axis)
 
         # microbatches replicated per stage group
-        return shard_map_nocheck(per_stage, mesh=mesh,
-                                 in_specs=P(*([None] * x.ndim)),
-                                 out_specs=P(*([None] * x.ndim)))(x)
+        return jax.shard_map(per_stage, mesh=mesh,
+                             in_specs=P(*([None] * x.ndim)),
+                             out_specs=P(*([None] * x.ndim)),
+                             check_vma=False)(x)
 
     return pipelined
 

@@ -20,7 +20,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 NEG_INF = -1e30
 
@@ -91,7 +90,7 @@ def decode_attention(q, cache_k, cache_v, kv_len, *, blk_k: int = 512,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q, cache_k, cache_v)

@@ -23,7 +23,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 NEG_INF = -1e30
 
@@ -105,7 +104,7 @@ def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 128,
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q, D), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

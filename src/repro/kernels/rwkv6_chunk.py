@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref,
                  sout_ref, state_scr, *, nc: int, chunk: int):
@@ -106,7 +104,7 @@ def wkv6_chunked(r, k, v, lw, u, state0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, K, K), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, lw, u, state0)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _ssd_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
                 state_scr, *, nc: int, chunk: int):
@@ -90,7 +88,7 @@ def ssd_chunked(x, dt, la, Bm, Cm, h0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, la, Bm, Cm, h0)

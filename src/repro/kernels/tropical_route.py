@@ -23,10 +23,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import compat
+from jax.experimental.pallas import tpu as pltpu
 
 INF = 3.0e38  # python literal: jnp scalars may not be captured by kernels
+
+
+def _first_argmin(x, m, col):
+    """Lowest column index holding the row minimum ``m`` of ``x``.
+    ``jnp.argmin``'s Mosaic lowering does not promise the first of tied
+    minima; the planners' (value, index) tie order needs it."""
+    return jnp.min(jnp.where(x == m[:, None], col, x.shape[1]), axis=1)
 
 
 def _route_kernel(starts_oh_ref, ends_ref, costs_ref, dist_ref, pred_ref, *,
@@ -37,19 +43,26 @@ def _route_kernel(starts_oh_ref, ends_ref, costs_ref, dist_ref, pred_ref, *,
     costs = costs_ref[...]                     # (blk_r, P)
     blk_r = costs.shape[0]
 
-    dist0 = jnp.full((blk_r, L + 1), INF, jnp.float32)
-    dist0 = dist0.at[:, 0].set(0.0)
+    # boundary 0 starts at distance 0: an iota mask, not ``.at[].set`` —
+    # Mosaic has no scatter lowering
+    dist0 = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (blk_r, L + 1), 1) == 0,
+        0.0, INF).astype(jnp.float32)
     pred0 = jnp.full((blk_r, L + 1), -1, jnp.int32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, costs.shape[1]), 1)
 
     def body(b, carry):
         dist, pred = carry
-        # d[start_p] for all p, via MXU: (blk_r, L+1) @ (L+1, P)
+        # d[start_p] for all p, via MXU: (blk_r, L+1) @ (L+1, P). HIGHEST:
+        # the MXU's default rounds f32 operands to bf16, which corrupts
+        # distances; at f32 precision a one-hot gather is exact
         d_start = jax.lax.dot_general(
             jnp.minimum(dist, INF), S, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         cand = jnp.where(ends == b, d_start + costs, INF)   # (blk_r, P)
         best = jnp.min(cand, axis=1)
-        arg = jnp.argmin(cand, axis=1).astype(jnp.int32)
+        arg = _first_argmin(cand, best, col)
         onehot_b = (jax.lax.iota(jnp.int32, L + 1) == b)[None, :]
         dist = jnp.where(onehot_b, best[:, None], dist)
         pred = jnp.where(onehot_b & (best < INF)[:, None], arg[:, None], pred)
@@ -102,7 +115,7 @@ def tropical_route(starts, ends, costs, *, total_layers: int,
             jax.ShapeDtypeStruct((r_total, L + 1), jnp.float32),
             jax.ShapeDtypeStruct((r_total, L + 1), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(starts_oh, ends[None, :].astype(jnp.int32), costs)
@@ -132,8 +145,9 @@ def _route_kernel_kbest(starts_oh_ref, ends_ref, costs_ref, dist_ref,
     costs = costs_ref[...]                     # (blk_r, P*K), K-replicated
     blk_r, PK = costs.shape
 
-    dist0 = jnp.full((blk_r, (L + 1) * K), INF, jnp.float32)
-    dist0 = dist0.at[:, 0].set(0.0)            # boundary 0, rank 0
+    dist0 = jnp.where(                         # boundary 0, rank 0
+        jax.lax.broadcasted_iota(jnp.int32, (blk_r, (L + 1) * K), 1) == 0,
+        0.0, INF).astype(jnp.float32)
     pedge0 = jnp.full((blk_r, (L + 1) * K), -1, jnp.int32)
     prank0 = jnp.full((blk_r, (L + 1) * K), -1, jnp.int32)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, PK), 1)
@@ -141,13 +155,14 @@ def _route_kernel_kbest(starts_oh_ref, ends_ref, costs_ref, dist_ref,
 
     def body(b, carry):
         dist, pedge, prank = carry
-        d_start = jax.lax.dot_general(
+        d_start = jax.lax.dot_general(                  # exact: HIGHEST
             jnp.minimum(dist, INF), S, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         cand = jnp.where(ends == b, d_start + costs, INF)   # (blk_r, PK)
         for k in range(K):
             m = jnp.min(cand, axis=1)
-            a = jnp.argmin(cand, axis=1).astype(jnp.int32)
+            a = _first_argmin(cand, m, col)
             ok = (m < INF)[:, None]
             tgt = outcol == b * K + k
             dist = jnp.where(tgt, jnp.where(ok, m[:, None], INF), dist)
@@ -215,7 +230,7 @@ def tropical_route_kbest(starts, ends, costs, *, total_layers: int,
             jax.ShapeDtypeStruct((r_total, (L + 1) * K), jnp.int32),
             jax.ShapeDtypeStruct((r_total, (L + 1) * K), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(starts_oh, ends_rep, costs_rep)

@@ -11,6 +11,14 @@ import jax
 from repro.configs.base import MeshConfig
 
 
+def _auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules here
+    are GSPMD constraints, which ``Explicit`` axes (the default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """(16,16) data×model single pod; (2,16,16) pod×data×model for 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -23,7 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devices)} — run via "
             "launch/dryrun.py (it sets xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_mesh_from_config(cfg: MeshConfig):
@@ -31,7 +39,7 @@ def make_mesh_from_config(cfg: MeshConfig):
     n = cfg.num_devices
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh(cfg.shape, cfg.axes, devices=devices[:n])
+    return _auto_mesh(cfg.shape, cfg.axes, devices[:n])
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
@@ -39,4 +47,4 @@ def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import GTRACConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.serving.api import SubmitSpec
 from repro.serving.engine import ServingEngine
@@ -172,6 +173,7 @@ def main(argv=None):
         ap.error("--disaggregate splits the window-batched serving loop "
                  "(run_queue); add --windowed")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(num_layers=4)

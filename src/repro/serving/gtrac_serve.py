@@ -27,6 +27,7 @@ This powers examples/serve_gtrac.py and the integration tests.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -42,10 +43,13 @@ from repro.core.registry import SeekerCache
 from repro.core.routing import ALGORITHMS
 from repro.core.sharding import make_registry
 from repro.core.types import HopReport
-from repro.distributed.pipeline import StagePartition
+from repro.distributed.pipeline import (
+    StagePartition,
+    slice_stage_params,
+    stage_forward,
+)
 from repro.models.common import apply_norm, embed_tokens, logits_head
 from repro.models.rope import positional_angles
-from repro.models.transformer import block_forward
 from repro.obs.metrics import MetricsRegistry, percentiles
 from repro.obs.trace import NOOP_TRACER, TraceBuffer, Tracer
 from repro.serving.api import SubmitSpec
@@ -62,34 +66,40 @@ from repro.sync.gossip import make_sync_plane
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "first", "last"))
+def stage_step(cfg: ModelConfig, stage_params, tokens, x, *, first: bool,
+               last: bool):
+    """One stage's forward. The weights are arguments, never constants
+    of the program, so every middle stage shares one executable per
+    prefix length. Stage 0 embeds ``tokens``; the last stage returns the
+    last position's logits instead of hidden states."""
+    B, S = tokens.shape
+    if first:
+        x = embed_tokens(cfg, stage_params["embed"], tokens)
+    pos = jnp.arange(S)[None, :].repeat(B, 0)
+    angles = (positional_angles(cfg, pos)
+              if cfg.pos_type in ("rope", "mrope") else None)
+    x = stage_forward(cfg, stage_params, x, angles)
+    if last:
+        x = apply_norm(cfg, stage_params["final_norm"], x)
+        return logits_head(cfg, stage_params["embed"], x[:, -1:, :])
+    return x
+
+
 def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition):
-    """One jitted fn per stage: stage 0 embeds, last stage emits logits."""
+    """One hop fn per stage over a ``(tokens, x)`` payload. Each stage's
+    weights are sliced once, here, and passed to ``stage_step``."""
     n = partition.n_stages
 
     def stage_fn(i: int):
-        s, e = partition.segment(i)
+        sp = slice_stage_params(params, *partition.segment(i))
+        flags = {"first": i == 0, "last": i == n - 1}
 
         def fn(payload):
-            tokens, x = payload                     # x may be None at stage 0
-            B, S = tokens.shape
-            if i == 0:
-                x = embed_tokens(cfg, params["embed"], tokens)
-            pos = jnp.arange(S)[None, :].repeat(B, 0)
-            angles = (positional_angles(cfg, pos)
-                      if cfg.pos_type in ("rope", "mrope") else None)
+            tokens, x = payload                     # x is None at stage 0
+            return tokens, stage_step(cfg, sp, tokens, x, **flags)
 
-            def body(x, lp):
-                x, _ = block_forward(cfg, lp, x, angles)
-                return x, None
-
-            lp = jax.tree.map(lambda a: a[s:e], params["layers"])
-            x, _ = jax.lax.scan(body, x, lp)
-            if i == n - 1:
-                x = apply_norm(cfg, params["final_norm"], x)
-                return tokens, logits_head(cfg, params["embed"], x[:, -1:, :])
-            return tokens, x
-
-        return jax.jit(fn)
+        return fn
 
     return [stage_fn(i) for i in range(n)]
 

@@ -54,7 +54,8 @@ def test_sharded_train_step_matches_single_device():
         # single device reference
         p_ref, _, m_ref = jax.jit(step)(params, o0, batch)
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with mesh, sh.activation_policy(mesh):
             ps = sh.param_shardings(mesh, params)
             bs = jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -87,7 +88,8 @@ def test_elastic_remesh_reshard():
                                                 vocab_size=64)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh0 = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh0 = jax.make_mesh((2, 4), ('data', 'model'),
+                              axis_types=(jax.sharding.AxisType.Auto,) * 2)
         from repro.distributed.sharding import param_shardings
         params = jax.device_put(params, param_shardings(mesh0, params))
         lost = [d.id for d in jax.devices()[:4]]
@@ -108,7 +110,8 @@ def test_pipeline_shard_map_matches_sequential():
         from repro.distributed.pipeline import pipeline_shard_map
 
         S = 4
-        mesh = jax.make_mesh((S,), ('stage',))
+        mesh = jax.make_mesh((S,), ('stage',),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 1)
         Ws = jax.random.normal(jax.random.PRNGKey(0), (S, 16, 16)) * 0.3
 
         def stage_fn(stage, x):
@@ -136,17 +139,17 @@ def test_compressed_psum_error_feedback():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import compressed_psum
-        from repro.distributed.compat import shard_map_nocheck
 
-        mesh = jax.make_mesh((8,), ('data',))
+        mesh = jax.make_mesh((8,), ('data',),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 1)
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
 
         def one_round(g, r):
             return compressed_psum(g, 'data', r)
 
-        f = shard_map_nocheck(one_round, mesh=mesh,
-                              in_specs=(P('data'), P('data')),
-                              out_specs=(P('data'), P('data')))
+        f = jax.shard_map(one_round, mesh=mesh,
+                          in_specs=(P('data'), P('data')),
+                          out_specs=(P('data'), P('data')), check_vma=False)
         want = jnp.mean(g, axis=0)
         r = jnp.zeros_like(g)
         acc_true = jnp.zeros(128)
@@ -178,7 +181,8 @@ def test_dryrun_single_cell_small_mesh():
         from repro.launch import roofline as rl
         from repro.launch.dryrun import build_lowerable
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeConfig('train_tiny', 128, 8, 'train')
         with mesh, sh.activation_policy(mesh):
             fn, args = build_lowerable(
@@ -204,7 +208,8 @@ def test_decode_cell_small_mesh():
         from repro.distributed import sharding as sh
         from repro.launch.dryrun import build_lowerable
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeConfig('decode_tiny', 256, 8, 'decode')
         with mesh, sh.activation_policy(mesh):
             fn, args = build_lowerable(

@@ -5,12 +5,14 @@ same tie-breaking — including tie-heavy cost landscapes, infeasible rows,
 and degenerate empty batches. Plans built from the device path must drive
 ``ChainExecutor`` failover splicing exactly like numpy-built plans.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.executor import ChainExecutor
 from repro.core.planner import RoutePlanner
+from repro.core.registry import AnchorRegistry
 from repro.core.routing_jax import (
     backtrack_kbest,
     effective_costs,
@@ -144,6 +146,27 @@ class TestThreeBackendParity:
         assert dev[0][0] == chains
 
 
+@pytest.mark.parametrize("kernel", ["tropical_route", "tropical_route_kbest"])
+def test_kernel_ops_are_exact_on_chip(kernel):
+    """Two chip-only faults interpret mode cannot show. The one-hot
+    boundary gather runs on the MXU, whose default rounds f32 operands to
+    bf16: every matmul must ask for HIGHEST. And Mosaic's argmin does not
+    promise the first of tied minima: the kernels must not use it."""
+    starts = jnp.zeros((8,), jnp.int32)
+    costs = jnp.zeros((4, 8), jnp.float32)
+    kw = {"k_best": 2} if kernel.endswith("kbest") else {}
+    fn = {"tropical_route": tropical_route,
+          "tropical_route_kbest": tropical_route_kbest}[kernel]
+    text = str(jax.make_jaxpr(
+        lambda s, e, c: fn(s, e, c, total_layers=6, **kw))(
+            starts, starts, costs))
+    n_dots = text.count("dot_general[")
+    assert n_dots >= 1
+    assert text.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") \
+        == n_dots
+    assert "argmin" not in text
+
+
 class TestDegenerateBatches:
     def test_kernel_empty_batch_regression(self):
         """R == 0 used to divide by zero in the grid computation; it must
@@ -165,6 +188,18 @@ class TestDegenerateBatches:
         hops, ck = route_batched_kbest(t, 12, gcfg, np.zeros((0,)),
                                        k_max=12, k_best=4)
         assert hops.shape == (0, 4, 12) and ck.shape == (0, 4)
+
+    @pytest.mark.parametrize("backend", ["numpy", "jnp", "pallas"])
+    def test_empty_registry_plans_infeasible(self, gcfg, backend):
+        """A table with no peers routes nothing on every backend: the
+        device DPs must not reduce over a zero-width peer axis."""
+        t = AnchorRegistry(gcfg).snapshot(0.0)
+        assert len(t) == 0
+        plans = plan_batched(t, 12, gcfg, np.array([0.0, 0.9]),
+                             planner=RoutePlanner(12, k_best=4), k_best=4,
+                             backend=backend,
+                             interpret=(backend == "pallas"))
+        assert [p.feasible for p in plans] == [False, False]
 
     def test_backtrack_kbest_shapes(self, gcfg, layered_anchor):
         t = layered_anchor.snapshot(0.0)

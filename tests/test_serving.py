@@ -8,10 +8,15 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import GTRACConfig
+from repro.distributed.pipeline import StagePartition
 from repro.models.api import build_model
 from repro.serving.api import SubmitSpec
 from repro.serving.engine import ServingEngine
-from repro.serving.gtrac_serve import GTRACPipelineServer, sample_token
+from repro.serving.gtrac_serve import (
+    GTRACPipelineServer,
+    make_stage_fns,
+    sample_token,
+)
 
 KEY = jax.random.PRNGKey(7)
 
@@ -70,6 +75,36 @@ class TestGTRACServer:
         want = monolithic_greedy(cfg, model, params, prompt, 5)
         assert list(out) == want
         assert met.failures == 0 and met.tokens == 5
+
+    def test_middle_stages_share_one_executable(self):
+        """Stage weights are arguments: five stages compile three programs
+        (first, middle, last) per prefix length, and the chain's logits
+        equal the monolithic model's."""
+        cfg = get_config("gpt2-large").reduced(num_layers=5, vocab_size=96)
+        model = build_model(cfg)
+        params = model.init(KEY)
+        fns = make_stage_fns(cfg, params, StagePartition.uniform(5, 1))
+        compiles = []
+
+        def count(name, _secs, **_kw):
+            if name == "/jax/core/compile/backend_compile_duration":
+                compiles.append(name)
+
+        toks = [jnp.arange(1, S + 1, dtype=jnp.int32)[None, :]
+                for S in (6, 7)]
+        jax.monitoring.register_event_duration_secs_listener(count)
+        try:
+            for tokens in toks:
+                payload = (tokens, None)
+                for fn in fns:
+                    payload = fn(payload)
+                jax.block_until_ready(payload)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(count)
+        assert len(compiles) == 3 * len(toks)
+        want, _ = model.prefill(params, tokens=toks[-1])
+        np.testing.assert_allclose(np.asarray(payload[1]), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
     def test_survives_injected_failures(self, tiny):
         """Honeypot-heavy peer pool: trust learning + repair keep serving."""
