@@ -1,0 +1,8 @@
+"""The benchmark's tests run on the CPU and import the program from
+``src`` as ``run.py`` does."""
+import sys
+from pathlib import Path
+
+SRC = str(Path(__file__).resolve().parents[2] / "src")
+if SRC not in sys.path:
+    sys.path.insert(0, SRC)
