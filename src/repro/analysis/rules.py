@@ -476,7 +476,9 @@ class VersionBumpRule(Rule):
 # ---------------------------------------------------------------------------
 
 _SPAN_METHODS = {"span", "begin", "end", "event", "add"}
-_TRACER_NAMES = {"tr", "tracer"}
+# the sim/rpc-domain tracer and the served path's host-clock tracer
+_TRACER_ATTRS = {"tracer", "host_tracer"}
+_TRACER_NAMES = {"tr", "tracer", "ht", "host_tracer"}
 
 
 @dataclass
@@ -494,7 +496,9 @@ class TracerGuardRule(Rule):
     or the ``sp = tr.begin(...) if tr.enabled else None`` no-op
     pattern). An unguarded call site pays dict/list work per request
     even with tracing off — and regresses exactly the hot paths
-    (routing, hedging, serving) the guards were added for.
+    (routing, hedging, serving) the guards were added for. The host
+    tracer (``host_tracer``, aliased ``ht``) spans every window phase
+    and every hop dispatch, so it is held to the same guard.
     """
 
     rule_id = "tracer-guard"
@@ -533,7 +537,7 @@ class TracerGuardRule(Rule):
         if not names:
             return
         v = node.value
-        if any(isinstance(n, ast.Attribute) and n.attr == "tracer"
+        if any(isinstance(n, ast.Attribute) and n.attr in _TRACER_ATTRS
                for n in ast.walk(v)) and not isinstance(v, ast.Call):
             sc.tracer_aliases.update(names)
         if any(isinstance(n, ast.Attribute) and n.attr == "enabled"
@@ -543,7 +547,7 @@ class TracerGuardRule(Rule):
             sc.span_aliases.update(names)   # sp = begin() if enabled else None
 
     def _is_tracer_receiver(self, recv: ast.AST) -> bool:
-        if isinstance(recv, ast.Attribute) and recv.attr == "tracer":
+        if isinstance(recv, ast.Attribute) and recv.attr in _TRACER_ATTRS:
             return True
         if isinstance(recv, ast.Name):
             return (recv.id in self._scope.tracer_aliases

@@ -10,10 +10,11 @@ components that sum to the measured latencies.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                percentiles)
-from repro.obs.trace import (NOOP_TRACER, NoopTracer, Span, TraceBuffer,
-                             Tracer)
+from repro.obs.trace import (HOST_DOMAIN, NOOP_SPAN, NOOP_TRACER, NoopTracer,
+                             Span, TraceBuffer, Tracer)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "percentiles",
-    "NOOP_TRACER", "NoopTracer", "Span", "TraceBuffer", "Tracer",
+    "HOST_DOMAIN", "NOOP_SPAN", "NOOP_TRACER", "NoopTracer", "Span",
+    "TraceBuffer", "Tracer",
 ]

@@ -9,11 +9,11 @@ accounting identity the trace-demo asserts):
 where the exec components further split into hop-exec (successful hop
 latencies) and failover (failed-hop detection latencies — repair work
 rides the successful-hop side because the spliced replacement hop DID
-run). Routing ``plan`` cost is reported separately in wall time: the
-sim clock does not advance while the batched DP runs, so plan cost is
-host overhead, not request latency. The staleness column is the worst
-gossip staleness (rounds) the request routed under — the
-trust-discount input, not a time quantum.
+run). Routing cost is reported separately, on the host clock, from the
+host domain's ``route`` spans: the sim clock does not advance while the
+batched DP runs, so plan cost is host overhead, not request latency.
+The staleness column is the worst gossip staleness (rounds) the
+request routed under — the trust-discount input, not a time quantum.
 
 ``itl_breakdown`` splits steady-state inter-token latency into own
 chain execution vs window drag (waiting for the window's slowest
@@ -31,7 +31,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 from repro.obs.export import span_dict
 from repro.obs.metrics import percentiles
-from repro.obs.trace import Span, TraceBuffer
+from repro.obs.trace import HOST_DOMAIN, Span, TraceBuffer
 
 
 def _as_dicts(src) -> List[dict]:
@@ -143,11 +143,13 @@ def itl_breakdown(src) -> dict:
 
 
 def plan_wall_summary(src) -> dict:
-    """Routing plan cost (host wall time — zero sim time) from the
-    ``route.plan`` events the batch router emits."""
+    """Routing cost per window on the host clock (zero sim time), from
+    the host domain's ``route`` spans (the window's submits and its
+    batched DP); cache hits from the sim domain's ``route.plan``
+    events."""
     spans = _as_dicts(src)
-    walls = [float(sp["attrs"].get("wall_us", 0.0)) for sp in spans
-             if sp["name"] == "route.plan"]
+    walls = [sp["dur_ms"] * 1e3 for sp in spans
+             if sp["domain"] == HOST_DOMAIN and sp["name"] == "route"]
     hits = sum(1 for sp in spans if sp["name"] == "route.plan"
                and sp["attrs"].get("cache_hit"))
     p50, p99 = percentiles(walls, (50, 99))

@@ -21,6 +21,15 @@ Three ways to record:
   explicit times (per-hop spans reconstructed from an ``ExecReport``'s
   latencies, so the hot path never pays per-hop clock reads).
 
+The host domain (``HOST_DOMAIN``) stamps the served path's own work on
+the host clock. A tracer built with ``annotate=True`` also mirrors every
+lexical span (``span()``, or ``begin(push=True)`` closed by ``end``) as a
+``jax.profiler.TraceAnnotation`` named ``gtrac.<name>``, entered and
+exited in strict LIFO order, so under an active profiler session the
+span lands in the same trace, on the same clock, as the device's ops.
+Post-hoc ``add()`` and ``event()`` spans are not annotated. JAX is
+imported only by an annotating tracer.
+
 Overhead contract: instrumentation points guard on ``tracer.enabled``;
 the shared ``NOOP_TRACER`` answers every call with one preallocated
 no-op span, so with tracing disabled the hot path pays a single
@@ -33,6 +42,11 @@ import itertools
 import time as _time
 from typing import Callable, Deque, List, Optional
 
+#: the clock domain of the served path's host work
+HOST_DOMAIN = "host"
+#: name prefix of the profiler annotations an annotating tracer enters
+ANNOTATION_PREFIX = "gtrac."
+
 
 class Span:
     """One traced interval. Mutable until exported — ``tracer.end`` and
@@ -41,7 +55,7 @@ class Span:
     ring."""
 
     __slots__ = ("span_id", "parent_id", "name", "cat", "domain",
-                 "t0", "t1", "attrs", "_tracer", "_pushed")
+                 "t0", "t1", "attrs", "_tracer", "_pushed", "_ann")
 
     def __init__(self, span_id: int, parent_id: Optional[int], name: str,
                  cat: str, domain: str, t0: float, attrs: dict):
@@ -55,6 +69,7 @@ class Span:
         self.attrs = attrs
         self._tracer: Optional["Tracer"] = None
         self._pushed = False
+        self._ann = None        # entered profiler annotation, if any
 
     @property
     def dur_s(self) -> float:
@@ -81,7 +96,9 @@ class Span:
 
 class _NoopSpan:
     """Shared, attribute-free stand-in: every ``NoopTracer`` call hands
-    back this one object, so disabled tracing allocates nothing."""
+    back this one object, so disabled tracing allocates nothing. A
+    guarded lexical site uses it directly:
+    ``with (tr.span("x") if tr.enabled else NOOP_SPAN):``."""
 
     __slots__ = ()
     span_id = 0
@@ -100,7 +117,7 @@ class _NoopSpan:
         return False
 
 
-_NOOP_SPAN = _NoopSpan()
+NOOP_SPAN = _NoopSpan()
 
 
 class TraceBuffer:
@@ -135,10 +152,15 @@ class Tracer:
 
     def __init__(self, sink: Optional[TraceBuffer] = None,
                  clock: Optional[Callable[[], float]] = None,
-                 domain: str = "main"):
+                 domain: str = "main", annotate: bool = False):
         self.sink = sink if sink is not None else TraceBuffer()
         self.clock = clock if clock is not None else _time.perf_counter
         self.domain = domain
+        self.annotate = bool(annotate)
+        self._annotation = None
+        if self.annotate:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation
         self._stack: List[Span] = []
 
     def scope(self, domain: str,
@@ -164,6 +186,9 @@ class Tracer:
         if push:
             sp._pushed = True
             self._stack.append(sp)
+            if self.annotate:
+                sp._ann = self._annotation(ANNOTATION_PREFIX + name)
+                sp._ann.__enter__()
         return sp
 
     def end(self, span: Span, t1: Optional[float] = None, **attrs) -> Span:
@@ -171,12 +196,16 @@ class Tracer:
         if attrs:
             span.attrs.update(attrs)
         if span._pushed:
-            # tolerate out-of-order ends: pop through to this span
+            # tolerate out-of-order ends: pop through to this span,
+            # closing the annotations of the spans above it first
             while self._stack:
                 top = self._stack.pop()
+                top._pushed = False
+                if top._ann is not None:
+                    top._ann.__exit__(None, None, None)
+                    top._ann = None
                 if top is span:
                     break
-            span._pushed = False
         self.sink.append(span)
         return span
 
@@ -217,19 +246,19 @@ class NoopTracer:
 
     def begin(self, name, cat="", t0=None, parent=None, push=False,
               **attrs):
-        return _NOOP_SPAN
+        return NOOP_SPAN
 
     def end(self, span, t1=None, **attrs):
-        return _NOOP_SPAN
+        return NOOP_SPAN
 
     def span(self, name, cat="", **attrs):
-        return _NOOP_SPAN
+        return NOOP_SPAN
 
     def event(self, name, cat="", t=None, parent=None, **attrs):
-        return _NOOP_SPAN
+        return NOOP_SPAN
 
     def add(self, name, t0, t1, cat="", parent=None, **attrs):
-        return _NOOP_SPAN
+        return NOOP_SPAN
 
 
 NOOP_TRACER = NoopTracer()

@@ -24,7 +24,6 @@ DPs): serving converts from O(tokens × DP) to O(windows × batched-DP).
 """
 from __future__ import annotations
 
-import time as _time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -35,7 +34,7 @@ from repro.core.planner import RoutePlan, RoutePlanner, _edge_disjoint_order
 from repro.core.routing_jax import route_batched_kbest
 from repro.core.trust import effective_cost_vec
 from repro.core.types import PeerTable
-from repro.obs.trace import NOOP_TRACER
+from repro.obs.trace import NOOP_SPAN, NOOP_TRACER
 
 _INF_THRESH = 1.0e38
 
@@ -150,9 +149,11 @@ class BatchRouter:
     interpret: bool = False
     k_best: Optional[int] = None
     stats: RouterStats = field(default_factory=RouterStats)
-    # sim-domain tracer: plan cost is HOST work that advances no sim
-    # time, so it ships as a zero-duration event carrying wall_us
+    # sim-domain tracer: planning advances no sim time, so it ships as
+    # a zero-duration event; its host cost is the host tracer's
+    # ``route.dp`` span (the DP call and its read-back)
     tracer: object = NOOP_TRACER
+    host_tracer: object = NOOP_TRACER
     _pending: List[Tuple[int, float, Tuple[int, ...]]] = \
         field(default_factory=list)
     _cache: Optional[Tuple[PeerTable, Tuple, List[RoutePlan]]] = None
@@ -184,8 +185,6 @@ class BatchRouter:
         pending, self._pending = self._pending, []
         if not pending:
             return {}
-        traced = self.tracer.enabled
-        wall0 = _time.perf_counter() if traced else 0.0
         group_of: Dict[Tuple[float, Tuple[int, ...]], int] = {}
         for _, tau, warm in pending:
             group_of.setdefault((tau, warm), 0)
@@ -212,20 +211,22 @@ class BatchRouter:
             plans = self._cache[2]
             self.stats.window_cache_hits += 1
         else:
-            plans = plan_batched(table, self.total_layers, self.cfg,
-                                 taus, planner=self.planner,
-                                 k_best=self.k_best, backend=self.backend,
-                                 interpret=self.interpret,
-                                 warm_masks=warm_masks,
-                                 kv_bonus=self.cfg.kv_reuse_bonus)
+            ht = self.host_tracer
+            with (ht.span("route.dp", rows=len(taus)) if ht.enabled
+                  else NOOP_SPAN):
+                plans = plan_batched(table, self.total_layers, self.cfg,
+                                     taus, planner=self.planner,
+                                     k_best=self.k_best, backend=self.backend,
+                                     interpret=self.interpret,
+                                     warm_masks=warm_masks,
+                                     kv_bonus=self.cfg.kv_reuse_bonus)
             self._cache = (table, key, plans)
             self.stats.device_calls += 1
             self.stats.unique_floors += len(taus)
             cache_hit = False
-        if traced:
+        if self.tracer.enabled:
             self.tracer.event(
                 "route.plan", cat="routing", requests=len(pending),
-                rows=len(taus), cache_hit=cache_hit,
-                wall_us=(_time.perf_counter() - wall0) * 1e6)
+                rows=len(taus), cache_hit=cache_hit)
         return {rid: plans[group_of[(tau, warm)]]
                 for rid, tau, warm in pending}

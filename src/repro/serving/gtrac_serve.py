@@ -28,6 +28,9 @@ This powers examples/serve_gtrac.py and the integration tests.
 from __future__ import annotations
 
 import functools
+import gc
+import time
+import weakref
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -51,7 +54,13 @@ from repro.distributed.pipeline import (
 from repro.models.common import apply_norm, embed_tokens, logits_head
 from repro.models.rope import positional_angles
 from repro.obs.metrics import MetricsRegistry, percentiles
-from repro.obs.trace import NOOP_TRACER, TraceBuffer, Tracer
+from repro.obs.trace import (
+    HOST_DOMAIN,
+    NOOP_SPAN,
+    NOOP_TRACER,
+    TraceBuffer,
+    Tracer,
+)
 from repro.serving.api import SubmitSpec
 from repro.serving.batch_router import BatchRouter
 from repro.serving.engine import AdmissionQueue, Request, _deprecated_submit
@@ -102,6 +111,49 @@ def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition):
         return fn
 
     return [stage_fn(i) for i in range(n)]
+
+
+#: JAX's monitoring event for one backend compile (a persistent-cache
+#: load fires it too)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def hook_process_spans(tracer) -> weakref.finalize:
+    """Record ``gc`` and ``compile`` spans on ``tracer``, from the
+    collector's callbacks and JAX's compile-duration events. Both carry
+    their own durations, so the spans are synthesized with ``add`` under
+    whatever span is open. Returns the callable that removes the hooks;
+    they hold ``tracer`` weakly and are also removed when it is freed,
+    so a server dropped without clearing its host tracer leaves no hook
+    behind."""
+    ref = weakref.ref(tracer)
+    gc_t0 = None
+
+    def on_gc(phase, info):
+        nonlocal gc_t0
+        tr = ref()
+        if tr is not None and tr.enabled:
+            if phase == "start":
+                gc_t0 = tr.clock()
+            elif gc_t0 is not None:
+                tr.add("gc", gc_t0, tr.clock(), cat="process",
+                       generation=info["generation"],
+                       collected=info["collected"])
+                gc_t0 = None
+
+    def on_duration(name, secs, **_kw):
+        tr = ref()
+        if name == COMPILE_EVENT and tr is not None and tr.enabled:
+            t1 = tr.clock()
+            tr.add("compile", t1 - secs, t1, cat="process")
+
+    def unhook():
+        gc.callbacks.remove(on_gc)
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+
+    gc.callbacks.append(on_gc)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return weakref.finalize(tracer, unhook)
 
 
 def sample_token(logits, rng: np.random.Generator,
@@ -347,6 +399,10 @@ class GTRACPipelineServer:
         self.trace: Optional[TraceBuffer] = None
         self.tracer = NOOP_TRACER
         self._req_spans: Dict[int, object] = {}
+        # the served path's own host work on the host clock
+        # (set_host_tracer); NOOP_TRACER unless set
+        self.host_tracer = NOOP_TRACER
+        self._unhook_process_spans = None
         if self.gcfg.trace_enabled:
             self.trace = TraceBuffer(self.gcfg.trace_capacity)
             self.tracer = Tracer(self.trace, clock=lambda: self.bed.now,
@@ -359,6 +415,23 @@ class GTRACPipelineServer:
             if self._cp is not None:
                 self._cp.set_tracer(self.tracer.scope(
                     "rpc", clock=self._cp.clock.monotonic))
+            self.set_host_tracer(self.tracer.scope(
+                HOST_DOMAIN, clock=time.perf_counter))
+
+    def set_host_tracer(self, tracer=None) -> None:
+        """Attach a host-clock tracer to the served path: ``run_queue``'s
+        window phases, each hop's stage dispatch and the router's DP,
+        plus ``gc`` and ``compile`` process spans while it is set (see
+        ``hook_process_spans``). Build it with ``annotate=True`` to see
+        the spans in a ``jax.profiler`` trace beside the device's ops.
+        ``None`` (or ``NOOP_TRACER``) clears it and removes the hooks."""
+        tracer = NOOP_TRACER if tracer is None else tracer
+        if self._unhook_process_spans is not None:
+            self._unhook_process_spans()
+            self._unhook_process_spans = None
+        self.host_tracer = self.router.host_tracer = tracer
+        if tracer.enabled:
+            self._unhook_process_spans = hook_process_spans(tracer)
 
     # -- hop adapter -----------------------------------------------------------
 
@@ -375,7 +448,10 @@ class GTRACPipelineServer:
                 detect = self.gcfg.request_timeout_ms * 0.25
                 return payload, detect, False
             stage = self._stage_of[peer.layer_start]
-            out = self.stage_fns[stage](payload)   # REAL compute
+            ht = self.host_tracer
+            with (ht.span("hop.dispatch", stage=stage) if ht.enabled
+                  else NOOP_SPAN):
+                out = self.stage_fns[stage](payload)   # REAL compute
             ntok = 1
             if kv_tracked:
                 prefix = int(payload[0].shape[1])
@@ -558,16 +634,18 @@ class GTRACPipelineServer:
     def _emit_token(self, req: RoutedRequest, tok: int,
                     t_emit: float) -> None:
         """Append one generated token and stamp its emission time."""
-        req.tokens = jnp.concatenate(
-            [req.tokens, jnp.full((1, 1), int(tok), jnp.int32)], axis=1)
-        req.output.append(int(tok))
-        req.metrics.tokens += 1
-        req.metrics.emit_ms.append(t_emit * 1e3)
-        if req.metrics.ttft_ms < 0:
-            req.metrics.ttft_ms = (t_emit - req.arrival_time) * 1e3
-        if (req.eos_id is not None and int(tok) == req.eos_id) or \
-                len(req.output) >= req.max_new_tokens:
-            req.done = True
+        ht = self.host_tracer
+        with (ht.span("emit") if ht.enabled else NOOP_SPAN):
+            req.tokens = jnp.concatenate(
+                [req.tokens, jnp.full((1, 1), int(tok), jnp.int32)], axis=1)
+            req.output.append(int(tok))
+            req.metrics.tokens += 1
+            req.metrics.emit_ms.append(t_emit * 1e3)
+            if req.metrics.ttft_ms < 0:
+                req.metrics.ttft_ms = (t_emit - req.arrival_time) * 1e3
+            if (req.eos_id is not None and int(tok) == req.eos_id) or \
+                    len(req.output) >= req.max_new_tokens:
+                req.done = True
 
     def _finish_stream(self, req: RoutedRequest) -> None:
         """Stream left the pools: reclaim KV slots and failure draws."""
@@ -602,15 +680,17 @@ class GTRACPipelineServer:
 
     def _apply_report(self, req: RoutedRequest, report) -> None:
         """Fold one chain execution's outcome into trust + metrics."""
-        anchor_rep = self._normalized_report(req.request_id, report)
-        for rep in split_reports(anchor_rep):
-            self.bed.anchor.apply_report(rep)
-        req.metrics.repairs += int(report.repaired)
-        req.metrics.rerouted += int(report.repaired)
-        stats = getattr(req.executor, "stats", None)
-        if stats is not None:         # hedged executor: surface counts
-            req.metrics.hedges_fired = stats.hedges_fired
-            req.metrics.hedges_won = stats.hedges_won
+        ht = self.host_tracer
+        with (ht.span("trust_fold") if ht.enabled else NOOP_SPAN):
+            anchor_rep = self._normalized_report(req.request_id, report)
+            for rep in split_reports(anchor_rep):
+                self.bed.anchor.apply_report(rep)
+            req.metrics.repairs += int(report.repaired)
+            req.metrics.rerouted += int(report.repaired)
+            stats = getattr(req.executor, "stats", None)
+            if stats is not None:         # hedged executor: surface counts
+                req.metrics.hedges_fired = stats.hedges_fired
+                req.metrics.hedges_won = stats.hedges_won
 
     def run_queue(self) -> List[RoutedRequest]:
         """Serve every queued stream to completion under continuous
@@ -629,213 +709,255 @@ class GTRACPipelineServer:
         compute), and the final chunk's logits yield the first token, at
         which point the now-warm stream joins the decode pool. When
         nothing is runnable the clock jumps to the next chunk completion
-        or pending arrival."""
+        or pending arrival.
+
+        With a host tracer set (``set_host_tracer``) the call is one
+        ``run_queue`` span and each window a ``window`` span holding its
+        phases: ``admit``, ``sync_view``, ``route`` (the DP itself is
+        ``route.dp``), then per stream ``execute`` (one ``hop.dispatch``
+        per stage call), ``trust_fold``, ``kv``, ``token_sync`` (the
+        device-to-host read of a token) and ``emit``, and last
+        ``finish``."""
+        ht = self.host_tracer
+        with (ht.span("run_queue") if ht.enabled else NOOP_SPAN):
+            served = self._serve_windows(ht)
+            for req in served:
+                self._fill_stream_metrics(req.metrics)
+        return served
+
+    def _serve_windows(self, ht) -> List[RoutedRequest]:
+        """``run_queue``'s window loop, spanned on the host tracer
+        ``ht``; returns the served streams."""
         served: List[RoutedRequest] = []
         active: List[RoutedRequest] = []      # decode pool
         prefill: List[RoutedRequest] = []     # dedicated prefill streams
         gcfg = self.gcfg
         tr = self.tracer
         traced = tr.enabled
+        hon = ht.enabled
         while active or prefill or len(self.admission):
-            now = self.bed.now
-            # admission sweeps the registry (per-shard fan-out when the
-            # anchor is sharded) before the window is admitted
-            admitted = self.admission.next_window(
-                capacity=self.admission.max_batch - len(active)
-                - len(prefill), now=now)
-            served += admitted
-            if traced:
-                for req in admitted:
-                    rsp = tr.begin("request", cat="request",
-                                   t0=req.arrival_time, rid=req.request_id)
-                    self._req_spans[req.request_id] = rsp
-                    if now > req.arrival_time:
-                        tr.add("queue.wait", req.arrival_time, now,
-                               cat="serve", parent=rsp, rid=req.request_id)
-            if gcfg.disaggregate:
-                pre, dec = AdmissionQueue.split_by_kind(
-                    admitted, gcfg.prefill_chunk_tokens)
-            else:
-                pre, dec = [], admitted
-            for req in pre:
-                req.busy_until = now
-            prefill += pre
-            active += dec
-            # promote prefill streams whose final chunk has completed:
-            # emit the pending first token (stamped at chunk completion)
-            # and hand the warm stream to the decode pool
-            waiting: List[RoutedRequest] = []
-            for req in prefill:
-                if req.prefill_pos >= int(req.tokens.shape[1]) \
-                        and req.busy_until <= now:
-                    self._emit_token(req, req._pending_tok, req.busy_until)
-                    if req.done:
-                        self._finish_stream(req)
-                    else:
-                        active.append(req)
+            with (ht.span("window") if hon else NOOP_SPAN):
+                now = self.bed.now
+                # admission sweeps the registry (per-shard fan-out when
+                # the anchor is sharded) before the window is admitted
+                with (ht.span("admit") if hon else NOOP_SPAN):
+                    admitted = self.admission.next_window(
+                        capacity=self.admission.max_batch - len(active)
+                        - len(prefill), now=now)
+                served += admitted
+                if traced:
+                    for req in admitted:
+                        rsp = tr.begin("request", cat="request",
+                                       t0=req.arrival_time,
+                                       rid=req.request_id)
+                        self._req_spans[req.request_id] = rsp
+                        if now > req.arrival_time:
+                            tr.add("queue.wait", req.arrival_time, now,
+                                   cat="serve", parent=rsp,
+                                   rid=req.request_id)
+                if gcfg.disaggregate:
+                    pre, dec = AdmissionQueue.split_by_kind(
+                        admitted, gcfg.prefill_chunk_tokens)
                 else:
-                    waiting.append(req)
-            prefill = waiting
-            # launch prefill chunks up to the per-window token budget —
-            # the decode token budget, so prefill can never claim more
-            # window capacity than a full decode batch would. The budget
-            # protects decode streams; when the decode pool is empty
-            # there is nothing to displace, so every runnable stream
-            # launches (chunk size stays capped at the decode budget)
-            budget = self.admission.max_batch if active else None
-            chunks: List[Tuple[RoutedRequest, int]] = []
-            for req in prefill:
-                if budget is not None and budget <= 0:
-                    break
-                if req.busy_until > now:
-                    continue                   # chunk still in flight
-                c = min(gcfg.prefill_chunk_tokens,
-                        int(req.tokens.shape[1]) - req.prefill_pos,
-                        self.admission.max_batch)
-                if budget is not None:
-                    c = min(c, budget)
-                    budget -= c
-                chunks.append((req, c))
-            if not active and not chunks:
-                # nothing runnable now: jump to the next chunk completion
-                # or the next arrival (bursty workloads)
-                targets = [r.busy_until for r in prefill]
-                nxt_arrival = self.admission.next_arrival()
-                if nxt_arrival is not None and nxt_arrival > now:
-                    targets.append(nxt_arrival)
-                if not targets:
-                    break
-                self.bed.advance(min(targets) - now)
-                continue
-            wsp = (tr.begin("serve.window", cat="window", t0=now, push=True,
-                            decode=len(active), prefill_launches=len(chunks))
-                   if traced else None)
-            table = self._sync_and_view()
-            self.kv.validate(table, gcfg.trust_floor)
-            stale_rounds = (int(self.sync_seeker.staleness_rounds(
-                self.bed.now).max()) if self.sync_seeker is not None else 0)
-            for req in active + [r for r, _ in chunks]:
-                self.router.submit(req.request_id, req.tau,
-                                   warm_ids=self.kv.warm_ids(req.request_id))
-                req.metrics.stale_rounds_max = max(
-                    req.metrics.stale_rounds_max, stale_rounds)
-            plans = self.router.route_window(table)   # ONE batched DP
-            # -- prefill chunk launches (asynchronous: charge busy_until,
-            #    the decode window below does not wait for them) --------
-            fail_ms = 0.0
-            for req, c in chunks:
-                plan = plans[req.request_id]
-                if not plan.feasible:
-                    req.metrics.infeasible += 1
-                    req.done = True
-                    continue
-                end = req.prefill_pos + c
-                prev_busy = req.busy_until
-                report, out = req.executor.execute(
-                    plan.chain_ids(0), table,
-                    payload=(req.tokens[:, :end], None), plan=plan)
-                self._apply_report(req, report)
-                if traced:
-                    psp = self._req_spans.get(req.request_id)
-                    if now - prev_busy > 1e-12:
-                        # window-cadence gap between the previous chunk
-                        # completing and this launch
-                        tr.add("prefill.stall", prev_busy, now,
-                               cat="prefill", parent=psp,
-                               rid=req.request_id)
-                    csp = tr.add("prefill.chunk", now,
-                                 now + report.total_latency_ms / 1e3,
-                                 cat="prefill", parent=psp,
-                                 rid=req.request_id, tokens=c,
-                                 ok=report.success)
-                    self._trace_hops(csp, now, report)
-                if not report.success:
-                    req.metrics.failures += 1
-                    req.done = True
-                    fail_ms = max(fail_ms, report.total_latency_ms)
-                    continue
-                self.kv.record(req.request_id, report.chain, end)
-                req.metrics.prefill_chunks += 1
-                req.metrics.prefill_tokens += c
-                req.prefill_pos = end
-                req.busy_until = now + report.total_latency_ms / 1e3
-                if end == int(req.tokens.shape[1]):
-                    _, logits = out            # final chunk: first token
-                    req._pending_tok = int(jnp.argmax(logits[:, -1, :], -1)[0])
-            # -- decode window: one token per stream --------------------
-            window_ms = 0.0
-            w_spans: List[Tuple[object, float]] = []
-            for req in active:
-                plan = plans[req.request_id]
-                if not plan.feasible:
-                    req.metrics.infeasible += 1
-                    req.done = True
-                    continue
-                prefix = int(req.tokens.shape[1])
-                report, payload = req.executor.execute(
-                    plan.chain_ids(0), table, payload=(req.tokens, None),
-                    plan=plan)
-                self._apply_report(req, report)
-                window_ms = max(window_ms, report.total_latency_ms)
-                if traced:
-                    ssp = tr.add("decode.step", now,
-                                 now + report.total_latency_ms / 1e3,
-                                 cat="decode",
-                                 parent=self._req_spans.get(req.request_id),
-                                 rid=req.request_id, emitted=report.success,
-                                 first_token=(report.success
-                                              and req.metrics.ttft_ms < 0))
-                    self._trace_hops(ssp, now, report)
-                    w_spans.append((ssp, report.total_latency_ms))
-                if not report.success:
-                    req.metrics.failures += 1
-                    req.done = True
-                    continue
-                # reuse accounting: only steps where the stream HAD warm
-                # KV somewhere count — a first-contact step (inline
-                # prefill, nothing recorded yet) is neither hit nor miss
-                if self.kv.warm_ids(req.request_id):
-                    if self.kv.chain_warm(req.request_id, report.chain,
-                                          prefix - 1):
-                        req.metrics.kv_warm_hits += 1
+                    pre, dec = [], admitted
+                for req in pre:
+                    req.busy_until = now
+                prefill += pre
+                active += dec
+                # promote prefill streams whose final chunk has completed:
+                # emit the pending first token (stamped at chunk
+                # completion) and hand the warm stream to the decode pool
+                waiting: List[RoutedRequest] = []
+                for req in prefill:
+                    if req.prefill_pos >= int(req.tokens.shape[1]) \
+                            and req.busy_until <= now:
+                        self._emit_token(req, req._pending_tok,
+                                         req.busy_until)
+                        if req.done:
+                            self._finish_stream(req)
+                        else:
+                            active.append(req)
                     else:
-                        req.metrics.kv_cold_steps += 1
-                self.kv.record(req.request_id, report.chain, prefix)
-                _, logits = payload
-                tok = int(jnp.argmax(logits[:, -1, :], -1)[0])
-                req.metrics.token_latency_ms.append(report.total_latency_ms)
-                self._emit_token(req, tok,
-                                 now + report.total_latency_ms / 1e3)
-            # decode streams run concurrently: the clock advances by the
-            # window's max decode latency; a pure-prefill window advances
-            # to its earliest chunk completion instead
-            if traced:
-                # drag: the batch-synchronization gap between a stream's
-                # own step finishing and the window's max latency — it
-                # delays the stream's NEXT token, so ITL_k+1 = exec_k+1 +
-                # drag_k (obs.report.itl_breakdown). Known only once the
-                # window closes, hence the late stamp.
-                for ssp, own in w_spans:
-                    ssp.set(drag_ms=window_ms - own)
-            if active:
-                self.bed.advance(window_ms / 1e3)
-            elif chunks:
-                # ALL in-flight streams, not just this window's launches —
-                # an earlier chunk may complete (and promote) first
-                waits = [r.busy_until for r in prefill
-                         if not r.done and r.busy_until > now]
-                self.bed.advance((min(waits) - now) if waits
-                                 else fail_ms / 1e3)
-            if traced:
-                tr.end(wsp, t1=self.bed.now, window_ms=window_ms)
-            for req in active:
-                if req.done:
-                    self._finish_stream(req)
-            for req, _ in chunks:
-                if req.done:
-                    self._finish_stream(req)
-            active = [r for r in active if not r.done]
-            prefill = [r for r in prefill if not r.done]
-        for req in served:
-            self._fill_stream_metrics(req.metrics)
+                        waiting.append(req)
+                prefill = waiting
+                # launch prefill chunks up to the per-window token budget
+                # — the decode token budget, so prefill can never claim
+                # more window capacity than a full decode batch would.
+                # The budget protects decode streams; when the decode
+                # pool is empty there is nothing to displace, so every
+                # runnable stream launches (chunk size stays capped at
+                # the decode budget)
+                budget = self.admission.max_batch if active else None
+                chunks: List[Tuple[RoutedRequest, int]] = []
+                for req in prefill:
+                    if budget is not None and budget <= 0:
+                        break
+                    if req.busy_until > now:
+                        continue               # chunk still in flight
+                    c = min(gcfg.prefill_chunk_tokens,
+                            int(req.tokens.shape[1]) - req.prefill_pos,
+                            self.admission.max_batch)
+                    if budget is not None:
+                        c = min(c, budget)
+                        budget -= c
+                    chunks.append((req, c))
+                if not active and not chunks:
+                    # nothing runnable now: jump to the next chunk
+                    # completion or the next arrival (bursty workloads)
+                    targets = [r.busy_until for r in prefill]
+                    nxt_arrival = self.admission.next_arrival()
+                    if nxt_arrival is not None and nxt_arrival > now:
+                        targets.append(nxt_arrival)
+                    if not targets:
+                        break
+                    self.bed.advance(min(targets) - now)
+                    continue
+                wsp = (tr.begin("serve.window", cat="window", t0=now,
+                                push=True, decode=len(active),
+                                prefill_launches=len(chunks))
+                       if traced else None)
+                with (ht.span("sync_view") if hon else NOOP_SPAN):
+                    table = self._sync_and_view()
+                    self.kv.validate(table, gcfg.trust_floor)
+                    stale_rounds = (int(self.sync_seeker.staleness_rounds(
+                        self.bed.now).max())
+                        if self.sync_seeker is not None else 0)
+                with (ht.span("route") if hon else NOOP_SPAN):
+                    for req in active + [r for r, _ in chunks]:
+                        self.router.submit(
+                            req.request_id, req.tau,
+                            warm_ids=self.kv.warm_ids(req.request_id))
+                        req.metrics.stale_rounds_max = max(
+                            req.metrics.stale_rounds_max, stale_rounds)
+                    plans = self.router.route_window(table)  # ONE DP
+                # -- prefill chunk launches (asynchronous: charge
+                #    busy_until, the decode window below does not wait
+                #    for them) --------------------------------------------
+                fail_ms = 0.0
+                for req, c in chunks:
+                    plan = plans[req.request_id]
+                    if not plan.feasible:
+                        req.metrics.infeasible += 1
+                        req.done = True
+                        continue
+                    end = req.prefill_pos + c
+                    prev_busy = req.busy_until
+                    with (ht.span("execute") if hon else NOOP_SPAN):
+                        report, out = req.executor.execute(
+                            plan.chain_ids(0), table,
+                            payload=(req.tokens[:, :end], None), plan=plan)
+                    self._apply_report(req, report)
+                    if traced:
+                        psp = self._req_spans.get(req.request_id)
+                        if now - prev_busy > 1e-12:
+                            # window-cadence gap between the previous
+                            # chunk completing and this launch
+                            tr.add("prefill.stall", prev_busy, now,
+                                   cat="prefill", parent=psp,
+                                   rid=req.request_id)
+                        csp = tr.add("prefill.chunk", now,
+                                     now + report.total_latency_ms / 1e3,
+                                     cat="prefill", parent=psp,
+                                     rid=req.request_id, tokens=c,
+                                     ok=report.success)
+                        self._trace_hops(csp, now, report)
+                    if not report.success:
+                        req.metrics.failures += 1
+                        req.done = True
+                        fail_ms = max(fail_ms, report.total_latency_ms)
+                        continue
+                    with (ht.span("kv") if hon else NOOP_SPAN):
+                        self.kv.record(req.request_id, report.chain, end)
+                    req.metrics.prefill_chunks += 1
+                    req.metrics.prefill_tokens += c
+                    req.prefill_pos = end
+                    req.busy_until = now + report.total_latency_ms / 1e3
+                    if end == int(req.tokens.shape[1]):
+                        _, logits = out        # final chunk: first token
+                        with (ht.span("token_sync") if hon else NOOP_SPAN):
+                            req._pending_tok = int(
+                                jnp.argmax(logits[:, -1, :], -1)[0])
+                # -- decode window: one token per stream ----------------
+                window_ms = 0.0
+                w_spans: List[Tuple[object, float]] = []
+                for req in active:
+                    plan = plans[req.request_id]
+                    if not plan.feasible:
+                        req.metrics.infeasible += 1
+                        req.done = True
+                        continue
+                    prefix = int(req.tokens.shape[1])
+                    with (ht.span("execute") if hon else NOOP_SPAN):
+                        report, payload = req.executor.execute(
+                            plan.chain_ids(0), table,
+                            payload=(req.tokens, None), plan=plan)
+                    self._apply_report(req, report)
+                    window_ms = max(window_ms, report.total_latency_ms)
+                    if traced:
+                        ssp = tr.add(
+                            "decode.step", now,
+                            now + report.total_latency_ms / 1e3,
+                            cat="decode",
+                            parent=self._req_spans.get(req.request_id),
+                            rid=req.request_id, emitted=report.success,
+                            first_token=(report.success
+                                         and req.metrics.ttft_ms < 0))
+                        self._trace_hops(ssp, now, report)
+                        w_spans.append((ssp, report.total_latency_ms))
+                    if not report.success:
+                        req.metrics.failures += 1
+                        req.done = True
+                        continue
+                    # reuse accounting: only steps where the stream HAD
+                    # warm KV somewhere count — a first-contact step
+                    # (inline prefill, nothing recorded yet) is neither
+                    # hit nor miss
+                    with (ht.span("kv") if hon else NOOP_SPAN):
+                        if self.kv.warm_ids(req.request_id):
+                            if self.kv.chain_warm(req.request_id,
+                                                  report.chain, prefix - 1):
+                                req.metrics.kv_warm_hits += 1
+                            else:
+                                req.metrics.kv_cold_steps += 1
+                        self.kv.record(req.request_id, report.chain, prefix)
+                    _, logits = payload
+                    with (ht.span("token_sync") if hon else NOOP_SPAN):
+                        tok = int(jnp.argmax(logits[:, -1, :], -1)[0])
+                    req.metrics.token_latency_ms.append(
+                        report.total_latency_ms)
+                    self._emit_token(req, tok,
+                                     now + report.total_latency_ms / 1e3)
+                # decode streams run concurrently: the clock advances by
+                # the window's max decode latency; a pure-prefill window
+                # advances to its earliest chunk completion instead
+                if traced:
+                    # drag: the batch-synchronization gap between a
+                    # stream's own step finishing and the window's max
+                    # latency — it delays the stream's NEXT token, so
+                    # ITL_k+1 = exec_k+1 + drag_k (obs.report.
+                    # itl_breakdown). Known only once the window closes,
+                    # hence the late stamp.
+                    for ssp, own in w_spans:
+                        ssp.set(drag_ms=window_ms - own)
+                if active:
+                    self.bed.advance(window_ms / 1e3)
+                elif chunks:
+                    # ALL in-flight streams, not just this window's
+                    # launches — an earlier chunk may complete (and
+                    # promote) first
+                    waits = [r.busy_until for r in prefill
+                             if not r.done and r.busy_until > now]
+                    self.bed.advance((min(waits) - now) if waits
+                                     else fail_ms / 1e3)
+                if traced:
+                    tr.end(wsp, t1=self.bed.now, window_ms=window_ms)
+                with (ht.span("finish") if hon else NOOP_SPAN):
+                    for req in active:
+                        if req.done:
+                            self._finish_stream(req)
+                    for req, _ in chunks:
+                        if req.done:
+                            self._finish_stream(req)
+                active = [r for r in active if not r.done]
+                prefill = [r for r in prefill if not r.done]
         return served

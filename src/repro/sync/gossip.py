@@ -28,7 +28,6 @@ has aged out of the history get a full shard snapshot instead.
 """
 from __future__ import annotations
 
-import time as _time
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -208,8 +207,9 @@ class GossipScheduler:
     O(seekers)."""
 
     #: sim-domain tracer: rounds are instantaneous in sim time, so a
-    #: round span is zero-duration at ``now`` with the actual shipping
-    #: work recorded as wall_us on the per-ship events beneath it
+    #: round span is zero-duration at ``now`` with a zero-duration
+    #: event per ship beneath it; the host time of a serving window's
+    #: ticks is the host domain's ``sync_view`` span
     tracer = NOOP_TRACER
 
     def __init__(self, publisher: GossipPublisher,
@@ -422,7 +422,6 @@ class GossipScheduler:
 
     def _ship(self, seeker: SeekerCache, shard: int, now: float) -> None:
         traced = self.tracer.enabled
-        wall0 = _time.perf_counter() if traced else 0.0
         if self.relay is not None:
             # a ship IS direct anchor contact: refresh the seeker's
             # attestation store first, so what it is about to apply —
@@ -451,8 +450,7 @@ class GossipScheduler:
             self.tracer.event(
                 "gossip.delta", cat="gossip", t=now, shard=shard,
                 seeker=seeker.source_id, bytes=delta.wire_bytes(),
-                full=delta.is_full,
-                wall_us=(_time.perf_counter() - wall0) * 1e6)
+                full=delta.is_full)
         if self.verify and \
                 seeker.shard_digest(shard) != self.publisher.digest(shard):
             # the shipped-to mirror contradicts the root of trust: its

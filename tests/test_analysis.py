@@ -348,6 +348,40 @@ class TestTracerGuard:
         """
         assert findings_in(src, path="src/repro/serving/server.py") == []
 
+    def test_flags_unguarded_host_tracer_span(self):
+        # the host tracer spans every window phase and hop dispatch: an
+        # unguarded site builds kwargs and a span per token with tracing
+        # off, directly or through an alias
+        direct = """
+            def hop(self, payload):
+                with self.host_tracer.span("hop.dispatch", stage=1):
+                    return self.fn(payload)
+        """
+        alias = """
+            def run(self, reqs):
+                ht = self.host_tracer
+                for r in reqs:
+                    with ht.span("execute"):
+                        self.execute(r)
+        """
+        for src in (direct, alias):
+            fs = findings_in(src, path="src/repro/serving/server.py")
+            assert rule_ids(fs) == ["tracer-guard"]
+
+    def test_guarded_host_tracer_span_is_clean(self):
+        src = """
+            def run(self, reqs):
+                ht = self.host_tracer
+                hon = ht.enabled
+                for r in reqs:
+                    with (ht.span("execute") if hon else NOOP_SPAN):
+                        self.execute(r)
+                with (self.host_tracer.span("finish")
+                      if self.host_tracer.enabled else NOOP_SPAN):
+                    self.finish()
+        """
+        assert findings_in(src, path="src/repro/serving/server.py") == []
+
     def test_else_branch_of_guard_still_flags(self):
         src = """
             def route(self, req):

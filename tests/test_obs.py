@@ -1,7 +1,11 @@
 """Observability plane (repro.obs): tracer/metrics units, exact
 FakeClock span trees across the rpc boundary, executor failover/hedge
 markers, export round-trips, and the traced serving integration with
-its TTFT decomposition identity."""
+its TTFT decomposition identity; the served path's host-clock spans
+and their profiler annotations."""
+import gc
+import glob
+import itertools
 import json
 
 import numpy as np
@@ -28,8 +32,14 @@ from repro.obs.metrics import (
     MetricsRegistry,
     percentiles,
 )
-from repro.obs.report import itl_breakdown, ttft_breakdown
-from repro.obs.trace import NOOP_TRACER, TraceBuffer, Tracer
+from repro.obs.report import itl_breakdown, plan_wall_summary, ttft_breakdown
+from repro.obs.trace import (
+    HOST_DOMAIN,
+    NOOP_TRACER,
+    Span,
+    TraceBuffer,
+    Tracer,
+)
 
 
 @pytest.fixture
@@ -551,3 +561,295 @@ class TestTracedServing:
             tiled = sum(h.dur_s for h in hops
                         if h.parent_id == st.span_id)
             assert tiled == pytest.approx(st.dur_s)
+
+
+# ---------------------------------------------------------------------------
+# host-clock spans of the served path (host domain, profiler annotations)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Replace ``jax.profiler.TraceAnnotation`` with a recorder of every
+    annotation made, entered and exited."""
+    import jax
+    log = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            self.name = name
+            log.append(("new", name))
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return log
+
+
+def _counting_clock():
+    """An injected host clock: 0, 1, 2, ... one tick per read."""
+    ticks = itertools.count()
+    return lambda: float(next(ticks))
+
+
+def _golden_server(tiny_model, **gkw):
+    """Fault-free fleet: every hop dispatches its stage."""
+    from repro.serving.gtrac_serve import GTRACPipelineServer
+    cfg, params = tiny_model
+    return GTRACPipelineServer(cfg, params, layers_per_stage=2,
+                               replicas={"golden": 2},
+                               gcfg=GTRACConfig(**gkw), seed=3)
+
+
+def _tree(spans):
+    """(name, children) tuples of the spans' forest, in start order."""
+    kids = {}
+    for sp in sorted(spans, key=lambda s: (s.t0, s.span_id)):
+        kids.setdefault(sp.parent_id, []).append(sp)
+
+    def walk(pid):
+        return tuple((sp.name, walk(sp.span_id)) for sp in kids.get(pid, ()))
+
+    return walk(None)
+
+
+def _program_spans(buf):
+    """The host domain's spans of the served path itself: without the
+    ``gc`` and ``compile`` process spans, which land wherever the
+    collector or the compiler happens to run."""
+    return [sp for sp in buf.spans
+            if sp.domain == HOST_DOMAIN and sp.cat != "process"]
+
+
+class TestHostTracer:
+    def test_annotations_enter_and_exit_lifo(self, annotations):
+        tr = Tracer(TraceBuffer(), clock=_counting_clock(),
+                    domain=HOST_DOMAIN, annotate=True)
+        with tr.span("window"):
+            with tr.span("admit"):
+                pass
+            execute = tr.begin("execute", push=True)
+            tr.begin("hop.dispatch", push=True)
+            tr.end(execute)               # out of order: closes the hop
+            tr.add("gc", 0.0, 1.0)        # post-hoc: never annotated
+            tr.event("marker")
+        entered = [(k, n) for k, n in annotations if k != "new"]
+        assert entered == [
+            ("enter", "gtrac.window"), ("enter", "gtrac.admit"),
+            ("exit", "gtrac.admit"), ("enter", "gtrac.execute"),
+            ("enter", "gtrac.hop.dispatch"), ("exit", "gtrac.hop.dispatch"),
+            ("exit", "gtrac.execute"), ("exit", "gtrac.window")]
+        stack = []
+        for kind, name in entered:
+            if kind == "enter":
+                stack.append(name)
+            else:
+                assert stack.pop() == name
+        assert not stack and tr.current is None
+
+    def test_tracer_without_annotate_enters_none(self, annotations):
+        tr = Tracer(TraceBuffer(), clock=_counting_clock(),
+                    domain=HOST_DOMAIN)
+        with tr.span("window"):
+            tr.end(tr.begin("execute", push=True))
+        assert annotations == [] and len(tr.sink) == 2
+
+    def test_disabled_host_tracer_records_and_annotates_nothing(
+            self, tiny_model, annotations, monkeypatch):
+        from repro.serving.api import SubmitSpec
+        srv = _golden_server(tiny_model)
+        assert srv.host_tracer is NOOP_TRACER
+        assert srv.router.host_tracer is NOOP_TRACER
+        made = []
+        init = Span.__init__
+
+        def counted(self, *a, **kw):
+            made.append(a[2])
+            init(self, *a, **kw)
+
+        monkeypatch.setattr(Span, "__init__", counted)
+        srv.submit(SubmitSpec(prompt=np.arange(1, 9), max_new_tokens=2))
+        (req,) = srv.run_queue()
+        assert req.metrics.tokens == 2
+        assert made == [] and annotations == []
+
+    def test_exact_host_span_tree_of_run_queue(self, tiny_model):
+        from repro.serving.api import SubmitSpec
+        srv = _golden_server(tiny_model)
+        tr = Tracer(TraceBuffer(), clock=_counting_clock(),
+                    domain=HOST_DOMAIN)
+        srv.set_host_tracer(tr)
+        srv.submit(SubmitSpec(prompt=np.arange(1, 9), max_new_tokens=2))
+        (req,) = srv.run_queue()
+        srv.set_host_tracer(None)
+        assert req.metrics.tokens == 2
+        spans = _program_spans(tr.sink)
+        hops = (("hop.dispatch", ()), ("hop.dispatch", ()))
+        tail = (("execute", hops), ("trust_fold", ()), ("kv", ()),
+                ("token_sync", ()), ("emit", ()), ("finish", ()))
+        first = (("admit", ()), ("sync_view", ()),
+                 ("route", (("route.dp", ()),))) + tail
+        # the second window routes on the unchanged snapshot and floor:
+        # the router's window cache answers, so no DP runs
+        second = (("admit", ()), ("sync_view", ()), ("route", ())) + tail
+        assert _tree(spans) == (
+            ("run_queue", (("window", first), ("window", second))),)
+        by_id = {sp.span_id: sp for sp in spans}
+        for sp in spans:                  # children nest in time too
+            assert sp.t1 > sp.t0
+            if sp.parent_id is not None:
+                parent = by_id[sp.parent_id]
+                assert parent.t0 < sp.t0 and sp.t1 < parent.t1
+        assert [sp.attrs["stage"] for sp in spans
+                if sp.name == "hop.dispatch"] == [0, 1, 0, 1]
+
+    def test_one_dispatch_per_hop_and_one_sync_per_token(self, tiny_model):
+        """Across chunked prefill, failing peers and repairs: a stage
+        call for every hop that ran, a device-to-host read for every
+        token the streams emitted."""
+        from repro.serving.api import SubmitSpec
+        srv = _traced_server(tiny_model, disaggregate=True,
+                             prefill_chunk_tokens=4)
+        srv.set_host_tracer(srv.tracer.scope(HOST_DOMAIN,
+                                             clock=_counting_clock()))
+        for i in range(4):
+            srv.submit(SubmitSpec(prompt=np.arange(1, 9 + 4 * i),
+                                  max_new_tokens=4,
+                                  arrival_time=0.01 * i))
+        done = srv.run_queue()
+        host = _program_spans(srv.trace)
+        ran = [sp for sp in srv.trace.spans
+               if sp.name == "hop" and sp.attrs["ok"]]
+        failed = [sp for sp in srv.trace.spans
+                  if sp.name == "hop" and not sp.attrs["ok"]]
+        dispatched = [sp for sp in host if sp.name == "hop.dispatch"]
+        assert ran and failed, "the fleet should both serve and fail"
+        assert len(dispatched) == len(ran)
+        syncs = [sp for sp in host if sp.name == "token_sync"]
+        assert len(syncs) == sum(r.metrics.tokens for r in done) > 0
+        by_id = {sp.span_id: sp for sp in host}
+        for sp in dispatched:
+            assert by_id[sp.parent_id].name == "execute"
+
+    def test_gc_and_compile_spans_while_set(self):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.serving.gtrac_serve import hook_process_spans
+        tr = Tracer(TraceBuffer(), domain=HOST_DOMAIN)
+        unhook = hook_process_spans(tr)
+        with tr.span("window"):
+            gc.collect()
+            jax.jit(lambda x: x * 3.0 + 1.0)(jnp.arange(5.0))
+        unhook()
+        by_name = {}
+        for sp in tr.sink.spans:
+            by_name.setdefault(sp.name, []).append(sp)
+        (window,) = by_name["window"]
+        full = [sp for sp in by_name["gc"] if sp.attrs["generation"] == 2]
+        assert full and all(sp.cat == "process" for sp in by_name["gc"])
+        assert isinstance(full[0].attrs["collected"], int)
+        assert full[0].parent_id == window.span_id
+        assert full[0].t0 <= full[0].t1
+        compiles = by_name["compile"]      # the arange and the jit
+        assert compiles
+        for sp in compiles:
+            assert window.t0 <= sp.t0 <= sp.t1 <= window.t1
+        n = len(tr.sink)
+        gc.collect()                      # removed: nothing more lands
+        assert len(tr.sink) == n
+
+    def test_set_host_tracer_hooks_and_clears(self, tiny_model):
+        srv = _golden_server(tiny_model)
+        hooks = len(gc.callbacks)
+        tr = Tracer(TraceBuffer(), domain=HOST_DOMAIN)
+        srv.set_host_tracer(tr)
+        assert srv.host_tracer is tr and srv.router.host_tracer is tr
+        assert len(gc.callbacks) == hooks + 1
+        gc.collect()
+        assert any(sp.name == "gc" for sp in tr.sink.spans)
+        srv.set_host_tracer(None)
+        assert srv.host_tracer is NOOP_TRACER
+        assert srv.router.host_tracer is NOOP_TRACER
+        assert len(gc.callbacks) == hooks
+
+    def test_hooks_go_with_a_dropped_tracer(self, tiny_model):
+        srv = _golden_server(tiny_model)
+        hooks = len(gc.callbacks)
+        srv.set_host_tracer(Tracer(TraceBuffer(), domain=HOST_DOMAIN))
+        assert len(gc.callbacks) == hooks + 1
+        del srv
+        gc.collect()
+        assert len(gc.callbacks) == hooks
+
+    def test_traced_server_carries_serve_and_host_domains(self, tiny_model,
+                                                          tmp_path):
+        from repro.serving.api import SubmitSpec
+        srv = _traced_server(tiny_model)
+        assert srv.host_tracer.domain == HOST_DOMAIN
+        assert srv.host_tracer.sink is srv.trace
+        srv.submit(SubmitSpec(prompt=np.arange(1, 9), max_new_tokens=3))
+        srv.run_queue()
+        domains = {sp.domain for sp in srv.trace.spans}
+        assert {"serve", HOST_DOMAIN} <= domains
+        routes = [sp for sp in srv.trace.spans
+                  if sp.domain == HOST_DOMAIN and sp.name == "route"]
+        plan = plan_wall_summary(srv.trace)
+        assert plan["windows"] == len(routes) == 3
+        assert plan["wall_us_total"] == pytest.approx(
+            sum(sp.dur_s for sp in routes) * 1e6)
+        assert all("wall_us" not in sp.attrs for sp in srv.trace.spans)
+        path = str(tmp_path / "serve.trace.json")
+        export_chrome(srv.trace, path)
+        with open(path) as f:
+            events = json.load(f)["traceEvents"]
+        rows = {e["args"]["name"]: e["pid"] for e in events
+                if e["ph"] == "M"}
+        assert {"domain:serve", f"domain:{HOST_DOMAIN}"} <= set(rows)
+        assert rows["domain:serve"] != rows[f"domain:{HOST_DOMAIN}"]
+
+    def test_profiler_trace_holds_program_spans(self, tiny_model, tmp_path):
+        """Under a profiler session the annotated spans land in the
+        trace's host plane, nested as the program opened them."""
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.serving.api import SubmitSpec
+        srv = _golden_server(tiny_model)
+        srv.submit(SubmitSpec(prompt=np.arange(1, 9), max_new_tokens=2))
+        srv.set_host_tracer(Tracer(TraceBuffer(), domain=HOST_DOMAIN,
+                                   annotate=True))
+        with jax.profiler.trace(str(tmp_path)):
+            srv.run_queue()
+        srv.set_host_tracer(None)
+        (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+        events = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("gtrac."):
+                        events.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+
+        def inside(inner, outer):
+            return any(a <= s and e <= b for s, e in events[inner]
+                       for a, b in events[outer])
+
+        ((q0, q1),) = events["gtrac.run_queue"]
+        assert len(events["gtrac.window"]) == 2
+        for s, e in events["gtrac.window"]:
+            assert q0 <= s and e <= q1
+        assert len(events["gtrac.hop.dispatch"]) == 4
+        assert len(events["gtrac.token_sync"]) == 2
+        for name in ("admit", "route", "execute", "token_sync", "emit"):
+            assert inside(f"gtrac.{name}", "gtrac.window")
+        assert inside("gtrac.hop.dispatch", "gtrac.execute")
+        assert inside("gtrac.route.dp", "gtrac.route")
