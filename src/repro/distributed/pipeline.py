@@ -49,14 +49,6 @@ class StagePartition:
         return StagePartition(tuple(dict.fromkeys(bs)))
 
 
-def slice_stage_params(params, start: int, end: int, stacked_key="layers"):
-    """Extract a stage's slice of the layer-stacked params (+ shared refs)."""
-    out = dict(params)
-    out[stacked_key] = jax.tree.map(lambda a: a[start:end],
-                                    params[stacked_key])
-    return out
-
-
 def stage_forward(cfg: ModelConfig, stage_params, x, angles=None):
     """Run a contiguous block-stack segment on hidden states (B, S, d)."""
     from repro.models.transformer import block_forward

@@ -45,6 +45,10 @@ def init_attention(key, cfg: ModelConfig, d_in: Optional[int] = None) -> Params:
     }
 
 
+#: the weights ``qkv_proj`` and ``out_proj`` consume at the activation dtype
+MATMUL_WEIGHTS = ("wq", "wk", "wv", "wo")
+
+
 def qkv_proj(cfg: ModelConfig, p: Params, x):
     """x (B, S, d) -> q (B,S,Hq,D), k,v (B,S,Hkv,D)."""
     B, S, _ = x.shape

@@ -99,6 +99,11 @@ def init_embeddings(key, cfg: ModelConfig) -> Params:
     return p
 
 
+#: the tables ``embed_tokens`` and ``logits_head`` consume at the
+#: activation dtype (a take then a cast equals a cast then a take)
+EMBEDDING_TABLES = ("tok", "pos", "head")
+
+
 def embed_tokens(cfg: ModelConfig, p: Params, tokens, positions=None):
     """tokens (B, S) int32 -> (B, S, d) activations."""
     from repro.distributed.sharding import constrain

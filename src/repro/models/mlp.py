@@ -21,6 +21,10 @@ def init_mlp(key, cfg: ModelConfig, d_in=None, d_ff=None) -> Params:
     return p
 
 
+#: the weights ``apply_mlp`` consumes at the activation dtype
+MATMUL_WEIGHTS = ("wi", "wg", "wo")
+
+
 def apply_mlp(cfg: ModelConfig, p: Params, x):
     dt = x.dtype
     h = constrain(x @ p["wi"].astype(dt), "batch", "seq", "ff")

@@ -47,6 +47,11 @@ def init_moe(key, cfg: ModelConfig) -> Params:
     return p
 
 
+#: the expert stacks ``apply_moe`` consumes at the activation dtype; the
+#: router is consumed at float32 (``route_topk``) and is not among them
+MATMUL_WEIGHTS = ("wi", "wg", "wo")
+
+
 def route_topk(cfg: ModelConfig, p: Params, xf):
     """xf (T, d) -> gates (T, k) f32, idx (T, k) i32, router probs (T, E)."""
     logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))

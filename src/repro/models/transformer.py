@@ -16,8 +16,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import attention as attn, moe as moe_mod
+from repro.models import attention as attn, mlp, moe as moe_mod
 from repro.models.common import (
+    EMBEDDING_TABLES,
     Params,
     adtype,
     apply_norm,
@@ -27,6 +28,7 @@ from repro.models.common import (
     init_embeddings,
     init_norm,
     logits_head,
+    pdtype,
     scan_or_unroll,
     split_keys,
 )
@@ -106,6 +108,35 @@ def init(key, cfg: ModelConfig) -> Params:
         "layers": layers,              # leading axis = layer
         "final_norm": init_norm(cfg),
     }
+
+
+def activation_dtype_params(cfg: ModelConfig, params: Params) -> Params:
+    """``params`` with every weight that the forward consumes only at the
+    activation dtype cast to it, and every other leaf as stored.
+
+    Cast: the attention and FFN matmul weights and the embedding tables.
+    Their consumers cast them to the activation dtype at each use, so a
+    copy cast once gives the same numbers without a conversion per call.
+    Kept: norm weights and biases (the norms upcast them to float32) and
+    the MoE router (consumed at float32). A leaf's role is its name under
+    its parent (``embed``, ``attn``, ``ffn``), so the tree may be the
+    whole model or any part that keeps those parents, such as a stage's
+    slice of the layer stacks. Returns ``params`` itself when the two
+    dtypes agree."""
+    dt = adtype(cfg)
+    if dt == pdtype(cfg):
+        return params
+    ffn = moe_mod if cfg.family == "moe" else mlp
+    roles = {"embed": EMBEDDING_TABLES, "attn": attn.MATMUL_WEIGHTS,
+             "ffn": ffn.MATMUL_WEIGHTS}
+
+    def cast(path, leaf):
+        keys = [getattr(k, "key", None) for k in path[-2:]]
+        if len(keys) == 2 and keys[1] in roles.get(keys[0], ()):
+            return leaf.astype(dt)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 def _angles(cfg: ModelConfig, positions):

@@ -46,13 +46,10 @@ from repro.core.registry import SeekerCache
 from repro.core.routing import ALGORITHMS
 from repro.core.sharding import make_registry
 from repro.core.types import HopReport
-from repro.distributed.pipeline import (
-    StagePartition,
-    slice_stage_params,
-    stage_forward,
-)
+from repro.distributed.pipeline import StagePartition, stage_forward
 from repro.models.common import apply_norm, embed_tokens, logits_head
 from repro.models.rope import positional_angles
+from repro.models.transformer import activation_dtype_params
 from repro.obs.metrics import MetricsRegistry, percentiles
 from repro.obs.trace import (
     HOST_DOMAIN,
@@ -80,8 +77,9 @@ def stage_step(cfg: ModelConfig, stage_params, tokens, x, *, first: bool,
                last: bool):
     """One stage's forward. The weights are arguments, never constants
     of the program, so every middle stage shares one executable per
-    prefix length. Stage 0 embeds ``tokens``; the last stage returns the
-    last position's logits instead of hidden states."""
+    prefix length; ``served_stage_params`` gives them in the dtypes the
+    forward consumes. Stage 0 embeds ``tokens``; the last stage returns
+    the last position's logits instead of hidden states."""
     B, S = tokens.shape
     if first:
         x = embed_tokens(cfg, stage_params["embed"], tokens)
@@ -95,13 +93,58 @@ def stage_step(cfg: ModelConfig, stage_params, tokens, x, *, first: bool,
     return x
 
 
-def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition):
-    """One hop fn per stage over a ``(tokens, x)`` payload. Each stage's
-    weights are sliced once, here, and passed to ``stage_step``."""
-    n = partition.n_stages
+@functools.partial(jax.jit, static_argnames=("cfg", "size"))
+def _stage_layers(cfg: ModelConfig, layers, start, *, size: int):
+    """Layers ``[start, start + size)`` of the stacks, cast as
+    ``activation_dtype_params`` says. Slice and cast fuse, so no
+    float32 copy of a cast slice is made."""
+    return activation_dtype_params(cfg, jax.tree.map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, start, size), layers))
+
+
+def served_stage_params(cfg: ModelConfig, params,
+                        partition: StagePartition) -> List[dict]:
+    """Each stage's weights as ``stage_step`` takes them: its slice of
+    the layer stacks, and the embedding and final norm that all stages
+    share, with the matmul weights and embedding tables in the
+    activation dtype (``models.transformer.activation_dtype_params``).
+    The forward consumes those only at that dtype; held at float32, each
+    call of a stage program would convert its whole weight stack again.
+    ``params`` stay the masters and are not changed."""
+    shared = activation_dtype_params(
+        cfg, {k: v for k, v in params.items() if k != "layers"})
+    stages = []
+    for i in range(partition.n_stages):
+        s, e = partition.segment(i)
+        stages.append({**shared, "layers": _stage_layers(
+            cfg, params["layers"], s, size=e - s)})
+    return stages
+
+
+def weight_bytes(stages: Sequence[dict]) -> Dict[str, int]:
+    """Bytes of the stages' weights per dtype (``bf16``, ``f32``),
+    each array counted once however many stages share it."""
+    held = {id(a): a for sp in stages for a in jax.tree.leaves(sp)}
+    out: Dict[str, int] = {}
+    for a in held.values():
+        name = a.dtype.name.replace("bfloat", "bf").replace("float", "f")
+        out[name] = out.get(name, 0) + a.nbytes
+    return out
+
+
+def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition,
+                   obs: Optional[MetricsRegistry] = None):
+    """One hop fn per stage over a ``(tokens, x)`` payload, on the
+    weights of ``served_stage_params``, made once, here. ``obs`` gets
+    their bytes per dtype as gauges ``stage/weight_bytes_<dtype>``."""
+    stages = served_stage_params(cfg, params, partition)
+    if obs is not None:
+        for name, n in weight_bytes(stages).items():
+            obs.gauge(f"stage/weight_bytes_{name}").set(n)
+    n = len(stages)
 
     def stage_fn(i: int):
-        sp = slice_stage_params(params, *partition.segment(i))
+        sp = stages[i]
         flags = {"first": i == 0, "last": i == n - 1}
 
         def fn(payload):
@@ -310,7 +353,11 @@ class GTRACPipelineServer:
         self.algorithm = algorithm
         self.partition = StagePartition.uniform(cfg.num_layers,
                                                 layers_per_stage)
-        self.stage_fns = make_stage_fns(cfg, params, self.partition)
+        # the telemetry plane (below) starts with the stage weights'
+        # bytes per dtype
+        self.obs = MetricsRegistry()
+        self.stage_fns = make_stage_fns(cfg, params, self.partition,
+                                        self.obs)
         rng = np.random.default_rng(seed)
         # any Registry (core/sharding.py): monolithic anchor for
         # cfg.anchor_shards=1, hash-partitioned ShardedAnchorRegistry
@@ -380,7 +427,6 @@ class GTRACPipelineServer:
         # per-stream ServeMetrics relay/control-plane fields fill from
         # its snapshot (_fill_stream_metrics), not from hand-written
         # mirroring per layer
-        self.obs = MetricsRegistry()
         self.obs.expose("router", self.router.stats)
         if self.gossip is not None:
             self.obs.expose("gossip", self.gossip.stats)

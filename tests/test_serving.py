@@ -1,6 +1,8 @@
 """Serving tests: engine correctness + G-TRAC routed pipeline produces the
 same tokens as monolithic execution, and survives injected failures."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,12 +12,15 @@ from repro.configs import get_config
 from repro.configs.base import GTRACConfig
 from repro.distributed.pipeline import StagePartition
 from repro.models.api import build_model
+from repro.models.transformer import activation_dtype_params
 from repro.serving.api import SubmitSpec
 from repro.serving.engine import ServingEngine
 from repro.serving.gtrac_serve import (
     GTRACPipelineServer,
     make_stage_fns,
     sample_token,
+    served_stage_params,
+    stage_step,
 )
 
 KEY = jax.random.PRNGKey(7)
@@ -105,6 +110,60 @@ class TestGTRACServer:
         want, _ = model.prefill(params, tokens=toks[-1])
         np.testing.assert_allclose(np.asarray(payload[1]), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("arch", ["gpt2-large", "phi3.5-moe-42b-a6.6b"],
+                             ids=["gpt2-large", "moe"])
+    def test_served_stage_weights_in_activation_dtype(self, arch):
+        """The served stage copies hold the matmul weights and embedding
+        tables at the activation dtype and every other leaf as stored;
+        the chain's logits are bit-identical to those of the float32
+        slices, and the server's gauges count the bytes per dtype."""
+        cfg = get_config(arch).reduced(num_layers=4, vocab_size=128)
+        params = build_model(cfg).init(KEY)
+        part = StagePartition.uniform(cfg.num_layers, 2)
+        served = served_stage_params(cfg, params, part)
+        masters = [{**params, "layers": jax.tree.map(lambda a: a[s:e],
+                                                     params["layers"])}
+                   for s, e in map(part.segment, range(part.n_stages))]
+
+        def chain(stages):
+            tokens = jnp.arange(1, 10, dtype=jnp.int32)[None, :]
+            x = None
+            for i, sp in enumerate(stages):
+                x = stage_step(cfg, sp, tokens, x, first=i == 0,
+                               last=i == len(stages) - 1)
+            return np.asarray(x)
+
+        assert np.array_equal(chain(served), chain(masters))
+
+        act, par = jnp.dtype(cfg.activation_dtype), jnp.dtype(cfg.param_dtype)
+        assert act == jnp.bfloat16 and par == jnp.float32
+        embed, layers = served[0]["embed"], served[0]["layers"]
+        ffn = dict(layers["ffn"])
+        router = ffn.pop("router", None)
+        assert (router is None) == (cfg.family != "moe")
+        cast = [*embed.values(), *layers["attn"].values(), *ffn.values()]
+        kept = jax.tree.leaves([layers["norm1"], layers["norm2"],
+                                served[0]["final_norm"], router])
+        assert {a.dtype for a in cast} == {act}
+        assert {a.dtype for a in kept} == {par}
+        assert any(a.ndim == 1 for a in kept)        # biases or weights
+        assert all(sp["embed"] is embed for sp in served)
+
+        srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
+                                  replicas={"golden": 1}, seed=0)
+        snap = srv.obs.snapshot()
+        per_stage = [served[0]["embed"], served[0]["final_norm"],
+                     *(sp["layers"] for sp in served)]
+        for dt, name in ((act, "bf16"), (par, "f32")):
+            want = sum(a.nbytes for a in jax.tree.leaves(per_stage)
+                       if a.dtype == dt)
+            assert snap[f"stage/weight_bytes_{name}"] == want > 0
+
+        f32 = dataclasses.replace(cfg, activation_dtype="float32")
+        same = activation_dtype_params(f32, params)
+        assert all(a is b for a, b in zip(jax.tree.leaves(same),
+                                          jax.tree.leaves(params)))
 
     def test_survives_injected_failures(self, tiny):
         """Honeypot-heavy peer pool: trust learning + repair keep serving."""

@@ -9,6 +9,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,10 +17,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.distributed.pipeline import slice_stage_params
+from repro.distributed.pipeline import StagePartition
 from repro.kernels.tropical_route import tropical_route, tropical_route_kbest
 from repro.models.api import build_model
-from repro.serving.gtrac_serve import stage_step
+from repro.serving.gtrac_serve import served_stage_params, stage_step
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
 MAX_PROGRAM_TEXT = 1_000_000      # weights as constants would be GBs
@@ -78,22 +79,57 @@ def test_tropical_route_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("first,last", [(True, False), (False, False),
-                                        (False, True)],
-                         ids=["first", "middle", "last"])
-def test_gpt2_large_stage_compiles(one_chip, first, last):
-    """One full-width GPT-2 Large stage (2 layers) fits the chip, and its
-    program takes the weights as arguments instead of embedding them."""
-    cfg = get_config("gpt2-large")
-    shapes = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
-    stage = jax.eval_shape(lambda p: slice_stage_params(p, 0, 2), shapes)
+def _stage_program(cfg, stage, one_chip, first, last, S=250):
     stage = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), stage)
-    S = 24
     tokens = _spec((1, S), jnp.int32, one_chip)
     x = None if first else _spec((1, S, cfg.d_model), jnp.bfloat16, one_chip)
     lowered = stage_step.lower(cfg, stage, tokens, x, first=first, last=last)
     assert len(lowered.as_text()) < MAX_PROGRAM_TEXT
-    mem = lowered.compile().memory_analysis()
+    return lowered.compile()
+
+
+def _unfused_text(compiled) -> str:
+    """The program's text without its fused computations: the arrays it
+    takes and writes to memory, not the values a fusion keeps on chip."""
+    text = compiled.as_text()
+    fused = set(re.findall(r" fusion\(.*calls=(%[\w.\-]+)", text))
+    blocks = re.split(r"\n(?=%|ENTRY)", text)
+    return "\n".join(b for b in blocks if b.split(" ", 1)[0] not in fused)
+
+
+@pytest.mark.parametrize("layers", [2, 9])
+@pytest.mark.parametrize("first,last", [(True, False), (False, False),
+                                        (False, True)],
+                         ids=["first", "middle", "last"])
+def test_gpt2_large_stage_compiles(one_chip, first, last, layers):
+    """One full-width GPT-2 Large stage (2 or 9 layers), built as the
+    server builds it, fits the chip and takes the weights as arguments
+    instead of embedding them. No float32 weight stack or table is
+    passed to the program or written by it, as on float32 slices; it
+    takes at most 55% of their argument bytes and, at 9 layers, no
+    temporary copy of its stack."""
+    cfg = get_config("gpt2-large")
+    shapes = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
+    stages = jax.eval_shape(lambda p: served_stage_params(
+        cfg, p, StagePartition.uniform(cfg.num_layers, layers)), shapes)
+    i = 0 if first else -1 if last else 1
+    compiled = _stage_program(cfg, stages[i], one_chip, first, last)
+    mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert total < V5E_HBM_BYTES
+
+    masters = {**shapes, "layers": jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((layers, *a.shape[1:]), a.dtype),
+        shapes["layers"])}
+    f32 = _stage_program(cfg, masters, one_chip, first, last)
+    text, f32_text = _unfused_text(compiled), _unfused_text(f32)
+    d, f, v = cfg.d_model, cfg.d_ff, cfg.vocab_size
+    stacks = [f"f32[{layers},{d},{f}]", f"f32[{layers},{f},{d}]",
+              f"f32[{layers},{d},{d}]"]
+    assert all(s in f32_text for s in stacks)
+    assert not any(s in text for s in [*stacks, f"f32[{v},{d}]"])
+    assert (mem.argument_size_in_bytes
+            <= 0.55 * f32.memory_analysis().argument_size_in_bytes)
+    if layers == 9:
+        assert mem.temp_size_in_bytes < 10 * 1024 ** 2
