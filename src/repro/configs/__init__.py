@@ -28,14 +28,18 @@ _ARCH_MODULES: Dict[str, str] = {
     "qwen3-moe-30b-a3b": "qwen3_moe",
     "rwkv6-1.6b": "rwkv6_1_6b",
     "zamba2-2.7b": "zamba2_2_7b",
+    "zamba2-7b": "zamba2_7b",
     "whisper-large-v3": "whisper_large_v3",
     "qwen2-vl-7b": "qwen2_vl_7b",
     # the paper's own evaluation model (GPT-2 Large, 36 layers)
     "gpt2-large": "gpt2_large",
 }
 
-#: the ten assigned architectures (gpt2-large is extra: the paper's model)
-ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES if a != "gpt2-large"]
+#: models served by the chip benchmark, beside the assigned grid
+SERVED_ONLY = ("gpt2-large", "zamba2-7b")
+#: the ten assigned architectures
+ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES
+                             if a not in SERVED_ONLY]
 ALL_ARCHS: List[str] = list(_ARCH_MODULES)
 
 

@@ -41,7 +41,14 @@ class ModelConfig:
     ssm_expand: int = 2         # d_inner = expand * d_model
     ssm_head_dim: int = 64      # P, channels per SSM head
     ssm_conv_width: int = 4
-    attn_every: int = 0         # zamba2: shared attn block every N mamba blocks
+    ssm_n_groups: int = 1       # G: B/C groups; head h reads group h // (H/G)
+    ssm_chunk: int = 256        # SSD chunk length of the chunked scan
+    # zamba2: the layers that first run a shared attention+MLP block (in
+    # turn over ``num_mem_blocks`` blocks), each with its own rank-
+    # ``adapter_rank`` LoRA on that block's MLP and its own linear
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
 
     # --- RWKV6 ---
     rwkv_head_dim: int = 64
@@ -52,7 +59,7 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     mrope_sections: Tuple[int, int, int] = (0, 0, 0)  # qwen2-vl (t, h, w)
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm
-    act: str = "silu"           # silu (SwiGLU) | gelu (plain MLP)
+    act: str = "silu"           # silu (SwiGLU) | gelu (plain, tanh) | gelu_gated (exact)
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
 
@@ -128,12 +135,17 @@ class ModelConfig:
         if self.family == "hybrid":  # zamba2
             d_in = self.ssm_expand * d
             n_heads_ssm = d_in // self.ssm_head_dim
-            # in_proj d -> (2*d_in + 2*N + n_heads), depthwise conv, out_proj
-            mamba = (d * (2 * d_in + 2 * self.ssm_state + n_heads_ssm)
-                     + self.ssm_conv_width * (d_in + 2 * self.ssm_state)
-                     + d_in * d)
-            attn = d * hq + 2 * d * hkv + hq * d + 3 * d * self.d_ff
-            return emb + L * (mamba + 2 * d) + attn  # attn block SHARED (one copy)
+            gn = self.ssm_n_groups * self.ssm_state
+            # in_proj d -> (2*d_in + 2*G*N + n_heads), depthwise conv, out_proj
+            mamba = (d * (2 * d_in + 2 * gn + n_heads_ssm)
+                     + self.ssm_conv_width * (d_in + 2 * gn) + d_in * d)
+            # shared blocks read [x ; x0] (2d wide); one copy each
+            shared = 2 * d * hq + 4 * d * hkv + hq * d + 3 * d * f
+            # per hybrid layer: its linear and its LoRA on the shared MLP
+            hybrid = d * d + self.adapter_rank * (d + 2 * f)
+            return (emb + L * (mamba + 2 * d)
+                    + self.num_mem_blocks * shared
+                    + len(self.hybrid_layer_ids) * hybrid)
         raise ValueError(self.family)
 
     def active_param_count(self) -> int:
@@ -166,7 +178,9 @@ class ModelConfig:
         if self.family in ("ssm", "hybrid"):
             kw.update(ssm_state=16, ssm_head_dim=32, rwkv_head_dim=32)
         if self.family == "hybrid":
-            kw.update(attn_every=1, num_layers=2)
+            # two shared blocks, each used once; two B/C groups
+            kw.update(num_layers=4, hybrid_layer_ids=(1, 3), num_mem_blocks=2,
+                      adapter_rank=8, ssm_n_groups=2, ssm_chunk=8)
         if self.is_encoder_decoder:
             kw.update(enc_layers=2)
         if self.pos_type == "mrope":

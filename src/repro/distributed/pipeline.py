@@ -9,9 +9,10 @@ device group. Two execution modes:
   ``jax.lax.ppermute`` (the TPU analogue of the paper's peer-to-peer
   activation handoff — each handover is one ICI hop instead of an HTTP
   POST). Bubble fraction = (S-1)/(M+S-1) for S stages / M microbatches.
-* ``StagePartition`` — layer-range slicing of a full param tree so the
-  serving engine can place/execute stage shards independently (the
-  G-TRAC chain executor drives one jitted stage fn per hop).
+* ``StagePartition`` — the contiguous layer ranges of the stages; each
+  model family slices its own params and runs a stage's forward
+  (``models/api.Model.served_stages`` and ``stage_forward``), and the
+  G-TRAC chain executor drives one jitted stage fn per hop.
 """
 from __future__ import annotations
 
@@ -21,8 +22,6 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.configs.base import ModelConfig
 
 
 # ---------------------------------------------------------------------------
@@ -47,18 +46,6 @@ class StagePartition:
     def uniform(num_layers: int, layers_per_stage: int) -> "StagePartition":
         bs = list(range(0, num_layers, layers_per_stage)) + [num_layers]
         return StagePartition(tuple(dict.fromkeys(bs)))
-
-
-def stage_forward(cfg: ModelConfig, stage_params, x, angles=None):
-    """Run a contiguous block-stack segment on hidden states (B, S, d)."""
-    from repro.models.transformer import block_forward
-
-    def body(x, lp):
-        x, _ = block_forward(cfg, lp, x, angles)
-        return x, None
-
-    x, _ = jax.lax.scan(body, x, stage_params["layers"])
-    return x
 
 
 # ---------------------------------------------------------------------------

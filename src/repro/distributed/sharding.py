@@ -76,7 +76,7 @@ def _pspec_for(key: str, shape: Tuple[int, ...], stacked: bool) -> P:
     return P(*lead, *([None] * nd))
 
 
-_STACKED_ROOTS = {"layers", "mamba", "encoder", "decoder"}
+_STACKED_ROOTS = {"layers", "mamba", "shared", "hybrid", "encoder", "decoder"}
 
 
 def param_pspecs(params, serving: bool = False) -> object:

@@ -84,9 +84,9 @@ def wkv6(r, k, v, lw, u, state0, *, impl: str = "auto",
     return ref.wkv6_ref(r, k, v, lw, u, state0)
 
 
-def ssd(x, dt, la, Bm, Cm, h0, *, impl: str = "auto",
+def ssd(dx, la, Bm, Cm, h0, *, impl: str = "auto",
         interpret: bool = False, **kw):
     impl = _resolve("pallas" if interpret else impl)
     if impl == "pallas":
-        return _ssd_pallas(x, dt, la, Bm, Cm, h0, interpret=interpret, **kw)
-    return ref.ssd_ref(x, dt, la, Bm, Cm, h0)
+        return _ssd_pallas(dx, la, Bm, Cm, h0, interpret=interpret, **kw)
+    return ref.ssd_ref(dx, la, Bm, Cm, h0)

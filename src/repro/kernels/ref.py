@@ -141,17 +141,20 @@ def wkv6_ref(r, k, v, lw, u, state0):
 # ---------------------------------------------------------------------------
 
 
-def ssd_ref(x, dt, la, Bm, Cm, h0):
-    """x (B,S,H,P); dt,la (B,S,H); Bm,Cm (B,S,N); h0 (B,H,N,P)."""
+def ssd_ref(dx, la, Bm, Cm, h0):
+    """dx = dt·x (B,S,H,P); la (B,S,H); Bm,Cm (B,S,G,N), head h reading
+    group h // (H/G); h0 (B,H,N,P)."""
+    H, G = dx.shape[2], Bm.shape[2]
+    Bm, Cm = (jnp.repeat(m, H // G, axis=2) for m in (Bm, Cm))
+
     def step(h, inp):
-        xt, dtt, lat, Bt, Ct = inp
+        xt, lat, Bt, Ct = inp
         h = jnp.exp(lat)[..., None, None] * h + \
-            jnp.einsum("bn,bhp,bh->bhnp", Bt, xt, dtt)
-        y = jnp.einsum("bn,bhnp->bhp", Ct, h)
+            jnp.einsum("bhn,bhp->bhnp", Bt, xt)
+        y = jnp.einsum("bhn,bhnp->bhp", Ct, h)
         return h, y
 
-    xs = (x.transpose(1, 0, 2, 3), dt.transpose(1, 0, 2),
-          la.transpose(1, 0, 2), Bm.transpose(1, 0, 2),
-          Cm.transpose(1, 0, 2))
+    xs = (dx.transpose(1, 0, 2, 3), la.transpose(1, 0, 2),
+          Bm.transpose(1, 0, 2, 3), Cm.transpose(1, 0, 2, 3))
     h, ys = jax.lax.scan(step, h0, xs)
     return ys.transpose(1, 0, 2, 3), h

@@ -128,18 +128,22 @@ def build_lowerable(arch: str, shape: ShapeConfig, mesh,
 UNROLL_MAX_LAYERS = 32
 
 
+def _hybrid_period(cfg) -> int:
+    """Layers between a hybrid model's first two hybrid layers."""
+    ids = cfg.hybrid_layer_ids
+    return ids[1] - ids[0] if len(ids) > 1 else cfg.num_layers
+
+
 def _layer_scale_overrides(cfg, l: int) -> Dict[str, Any]:
-    if cfg.family == "hybrid":  # scale in shared-block groups
-        g = max(1, l // cfg.attn_every)
-        return {"num_layers": g * cfg.attn_every}
+    if cfg.family == "hybrid":  # the hybrid layers that fall in the cut
+        return {"num_layers": l, "hybrid_layer_ids": tuple(
+            i for i in cfg.hybrid_layer_ids if i < l)}
     if cfg.is_encoder_decoder:  # enc and dec scale together
         return {"num_layers": l, "enc_layers": l}
     return {"num_layers": l}
 
 
 def _layer_count(cfg, overrides) -> float:
-    if cfg.family == "hybrid":
-        return overrides.get("num_layers", cfg.num_layers) // cfg.attn_every
     return overrides.get("num_layers", cfg.num_layers)
 
 
@@ -170,15 +174,14 @@ def _cost_pass(arch, shape, mesh, base_overrides=None,
             out[f"wire/{k}"] = float(v)
         return out
 
-    full_layers = (cfg.num_layers // cfg.attn_every
-                   if cfg.family == "hybrid" else cfg.num_layers)
+    full_layers = cfg.num_layers
     deep = cfg.num_layers > UNROLL_MAX_LAYERS or \
         (cfg.is_encoder_decoder and cfg.num_layers + cfg.enc_layers >
          UNROLL_MAX_LAYERS)
     if not deep:
         return compile_costs({}), {"accounting": "full_unroll"}
     if cfg.family == "hybrid":
-        l1, l2 = 2 * cfg.attn_every, 4 * cfg.attn_every
+        l1, l2 = 2 * _hybrid_period(cfg), 4 * _hybrid_period(cfg)
     else:
         l1, l2 = 8, 16
     o1, o2 = _layer_scale_overrides(cfg, l1), _layer_scale_overrides(cfg, l2)

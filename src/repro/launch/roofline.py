@@ -126,7 +126,7 @@ def attention_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
     d_attn = cfg.num_heads * cfg.head_dim
     B, S = shape.global_batch, shape.seq_len
     if cfg.family == "hybrid":
-        layers = cfg.num_layers // max(1, cfg.attn_every)
+        layers = len(cfg.hybrid_layer_ids)
     elif cfg.is_encoder_decoder:
         layers = cfg.enc_layers + 2 * cfg.num_layers  # self + cross
     else:

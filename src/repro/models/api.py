@@ -5,6 +5,11 @@ implementation modules and exposes a uniform functional surface:
     model.loss_fn(params, batch)         -> scalar loss          (train_step)
     model.prefill(params, **inputs)      -> (logits, cache)      (prefill)
     model.decode_step(params, token, cache) -> (logits, cache)   (serve_step)
+    model.served_stages(params, segments) -> per-stage weights    (routed
+                                            pipeline, one per [start, end))
+    model.stage_forward(stage, tokens, x, first=, last=) -> x or logits
+    model.stage_bucket                   -> multiple to which stage hops may
+                                            right-pad positions (None: never)
     model.input_specs(shape)             -> ShapeDtypeStruct pytrees for the
                                             dry-run (no allocation)
 
@@ -16,7 +21,7 @@ embeddings, never raw pixels/waveforms.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +38,9 @@ class Model:
     loss_fn: Callable
     prefill: Callable
     decode_step: Callable
+    served_stages: Callable
+    stage_forward: Callable
+    stage_bucket: Optional[int] = None
 
     # ------------------------------------------------------------------
     def train_specs(self, shape: ShapeConfig) -> Dict[str, Any]:
@@ -122,8 +130,16 @@ _FAMILY_MODULES = {
 }
 
 
+def _not_routable(cfg: ModelConfig):
+    def refuse(*_a, **_kw):
+        raise NotImplementedError(f"the {cfg.family} family has no "
+                                  f"pipeline stages (ROADMAP R1)")
+    return refuse
+
+
 def build_model(cfg: ModelConfig) -> Model:
     mod = _FAMILY_MODULES[cfg.family]
+    staged = hasattr(mod, "stage_forward")
     return Model(
         cfg=cfg,
         init=lambda key: mod.init(key, cfg),
@@ -131,4 +147,9 @@ def build_model(cfg: ModelConfig) -> Model:
         prefill=lambda params, **kw: mod.prefill(cfg, params, **kw),
         decode_step=lambda params, token, cache: mod.decode_step(
             cfg, params, token, cache),
+        served_stages=(lambda params, segments: mod.served_stages(
+            cfg, params, segments)) if staged else _not_routable(cfg),
+        stage_forward=(lambda stage, tokens, x, **kw: mod.stage_forward(
+            cfg, stage, tokens, x, **kw)) if staged else _not_routable(cfg),
+        stage_bucket=getattr(mod, "STAGE_BUCKET", None),
     )

@@ -96,11 +96,13 @@ def _mask_bias(mask):
 
 
 def attention_direct(q, k, v, *, causal: bool, q_offset: int = 0,
-                     kv_len=None, window: int = 0, seq_shard: bool = False):
+                     kv_len=None, window: int = 0, seq_shard: bool = False,
+                     scale=None):
     """q (B,Sq,Hq,D); k,v (B,Sk,Hkv,D) -> (B,Sq,Hq,D).
 
     ``kv_len`` (scalar or (B,)) masks out cache positions >= kv_len.
     ``window`` > 0 restricts attention to the trailing window.
+    ``scale`` multiplies q.k (default 1/sqrt(D)).
     ``seq_shard``: sequence-parallel decode (flash-decoding layout): q is
     tiny, so replicate its heads and keep the SCORES sharded along the
     cache's sequence dimension — otherwise GSPMD all-gathers the whole
@@ -110,11 +112,12 @@ def attention_direct(q, k, v, *, causal: bool, q_offset: int = 0,
     """
     if seq_shard:
         return _attention_decode_sp(q, k, v, q_offset=q_offset,
-                                    kv_len=kv_len, window=window)
+                                    kv_len=kv_len, window=window, scale=scale)
     B, Sq, Hq, D = q.shape
     k = _repeat_kv(k, Hq)
     v = _repeat_kv(v, Hq)
-    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     q_pos = q_offset + jnp.arange(Sq)
@@ -135,7 +138,7 @@ def attention_direct(q, k, v, *, causal: bool, q_offset: int = 0,
 
 
 def _attention_decode_sp(q, k, v, *, q_offset=0, kv_len=None,
-                         window: int = 0):
+                         window: int = 0, scale=None):
     """Sequence-parallel decode attention (flash-decoding layout).
 
     q (B,Sq,Hq,D) is tiny → replicated across 'model'; the KV cache stays
@@ -150,7 +153,8 @@ def _attention_decode_sp(q, k, v, *, q_offset=0, kv_len=None,
     k = constrain(k, "batch", "seq_model", None, None)
     v = constrain(v, "batch", "seq_model", None, None)
     qg = q.reshape(B, Sq, Hkv, G, D)
-    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32) * scale
     scores = constrain(scores, "batch", None, None, None, "seq_model")
@@ -171,7 +175,7 @@ def _attention_decode_sp(q, k, v, *, q_offset=0, kv_len=None,
 
 def attention_chunked(q, k, v, *, causal: bool, chunk: int = 1024,
                       window: int = 0, unroll: bool = False,
-                      chunk_remat: bool = False):
+                      chunk_remat: bool = False, scale=None):
     """Online-softmax attention scanning over KV chunks (flash-style in XLA).
 
     Peak memory is (B, Hq, Sq, chunk) scores per step. ``unroll=True``
@@ -181,11 +185,13 @@ def attention_chunked(q, k, v, *, causal: bool, chunk: int = 1024,
     B, Sq, Hq, D = q.shape
     Sk = k.shape[1]
     if Sk % chunk != 0:  # fall back for ragged sizes
-        return attention_direct(q, k, v, causal=causal, window=window)
+        return attention_direct(q, k, v, causal=causal, window=window,
+                                scale=scale)
     k = _repeat_kv(k, Hq)
     v = _repeat_kv(v, Hq)
     n = Sk // chunk
-    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
     ks = k.reshape(B, n, chunk, Hq, D).swapaxes(0, 1)    # (n,B,c,Hq,D)
     vs = v.reshape(B, n, chunk, Hq, D).swapaxes(0, 1)
     q_pos = jnp.arange(Sq)
@@ -233,19 +239,22 @@ def attention_flash(q, k, v, *, causal: bool, interpret: bool = False):
 
 
 def attend(cfg: ModelConfig, q, k, v, *, causal: bool = True,
-           q_offset: int = 0, kv_len=None, window: int = 0):
-    """Dispatch on cfg.attn_impl and sequence length."""
+           q_offset: int = 0, kv_len=None, window: int = 0, scale=None):
+    """Dispatch on cfg.attn_impl and sequence length. ``scale``
+    multiplies q.k (default 1/sqrt(head_dim); the flash kernel takes
+    only the default)."""
     Sk = k.shape[1]
-    if cfg.attn_impl == "flash" and kv_len is None:
+    if cfg.attn_impl == "flash" and kv_len is None and scale is None:
         return attention_flash(q, k, v, causal=causal)
     if Sk > cfg.attn_chunk_threshold and kv_len is None and q_offset == 0:
         # cap the chunk count so the unrolled (dry-run) path stays compact
         chunk = max(cfg.attn_chunk_size, Sk // 8)
         return attention_chunked(q, k, v, causal=causal, chunk=chunk,
                                  window=window, unroll=not cfg.scan_layers,
-                                 chunk_remat=cfg.attn_chunk_remat)
+                                 chunk_remat=cfg.attn_chunk_remat,
+                                 scale=scale)
     return attention_direct(q, k, v, causal=causal, q_offset=q_offset,
-                            kv_len=kv_len, window=window)
+                            kv_len=kv_len, window=window, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +263,7 @@ def attend(cfg: ModelConfig, q, k, v, *, causal: bool = True,
 
 
 def decode_attend(cfg: ModelConfig, q, cache_k, cache_v, index,
-                  window: int = 0):
+                  window: int = 0, scale=None):
     """One-token decode: q (B,1,Hq,D) against cache (B,Smax,Hkv,D).
 
     ``index`` — number of valid positions already in the cache *including*
@@ -263,7 +272,7 @@ def decode_attend(cfg: ModelConfig, q, cache_k, cache_v, index,
     q_offset = (index - 1) if window else 0
     return attention_direct(q, cache_k, cache_v, causal=False,
                             kv_len=index, window=window, q_offset=q_offset,
-                            seq_shard=cfg.decode_seq_shard)
+                            seq_shard=cfg.decode_seq_shard, scale=scale)
 
 
 def cache_update(cache_k, cache_v, k_new, v_new, index, masked: bool = False):

@@ -104,6 +104,18 @@ def init_embeddings(key, cfg: ModelConfig) -> Params:
 EMBEDDING_TABLES = ("tok", "pos", "head")
 
 
+def cast_roles(params: Params, roles: Dict[str, Any], dtype) -> Params:
+    """``params`` with every leaf whose name is in ``roles[<its parent's
+    name>]`` cast to ``dtype``, and every other leaf as stored."""
+    def cast(path, leaf):
+        keys = [getattr(k, "key", None) for k in path[-2:]]
+        if len(keys) == 2 and keys[1] in roles.get(keys[0], ()):
+            return leaf.astype(dtype)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 def embed_tokens(cfg: ModelConfig, p: Params, tokens, positions=None):
     """tokens (B, S) int32 -> (B, S, d) activations."""
     from repro.distributed.sharding import constrain

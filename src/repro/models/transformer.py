@@ -10,7 +10,8 @@ embeddings + M-RoPE). Whisper / RWKV6 / Zamba2 live in their own modules.
 """
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ from repro.models.common import (
     Params,
     adtype,
     apply_norm,
+    cast_roles,
     chunked_cross_entropy,
     cross_entropy_loss,
     embed_tokens,
@@ -127,16 +129,53 @@ def activation_dtype_params(cfg: ModelConfig, params: Params) -> Params:
     if dt == pdtype(cfg):
         return params
     ffn = moe_mod if cfg.family == "moe" else mlp
-    roles = {"embed": EMBEDDING_TABLES, "attn": attn.MATMUL_WEIGHTS,
-             "ffn": ffn.MATMUL_WEIGHTS}
+    return cast_roles(params, {"embed": EMBEDDING_TABLES,
+                               "attn": attn.MATMUL_WEIGHTS,
+                               "ffn": ffn.MATMUL_WEIGHTS}, dt)
 
-    def cast(path, leaf):
-        keys = [getattr(k, "key", None) for k in path[-2:]]
-        if len(keys) == 2 and keys[1] in roles.get(keys[0], ()):
-            return leaf.astype(dt)
-        return leaf
 
-    return jax.tree_util.tree_map_with_path(cast, params)
+@functools.partial(jax.jit, static_argnames=("cfg", "size"))
+def _stage_layers(cfg: ModelConfig, layers, start, *, size: int):
+    """Layers ``[start, start + size)`` of the stacks, cast as
+    ``activation_dtype_params`` says. Slice and cast fuse, so no
+    float32 copy of a cast slice is made."""
+    return activation_dtype_params(cfg, jax.tree.map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, start, size), layers))
+
+
+def served_stages(cfg: ModelConfig, params: Params,
+                  segments: Sequence[Tuple[int, int]]) -> List[Params]:
+    """Each stage's weights as ``stage_forward`` takes them: its slice
+    of the layer stacks, and the embedding and final norm that all
+    stages share, cast as ``activation_dtype_params`` says."""
+    shared = activation_dtype_params(
+        cfg, {k: v for k, v in params.items() if k != "layers"})
+    return [{**shared, "layers": _stage_layers(cfg, params["layers"], s,
+                                               size=e - s)}
+            for s, e in segments]
+
+
+def stage_forward(cfg: ModelConfig, stage_params: Params, tokens, x, *,
+                  first: bool, last: bool):
+    """One stage's forward over its slice of the layer stacks. Stage 0
+    embeds ``tokens``; the last stage returns the last position's logits
+    instead of hidden states (B, S, d)."""
+    B, S = tokens.shape
+    if first:
+        x = embed_tokens(cfg, stage_params["embed"], tokens)
+    pos = jnp.arange(S)[None, :].repeat(B, 0)
+    angles = (positional_angles(cfg, pos)
+              if cfg.pos_type in ("rope", "mrope") else None)
+
+    def body(x, lp):
+        x, _ = block_forward(cfg, lp, x, angles)
+        return x, None
+
+    x, _ = jax.lax.scan(body, x, stage_params["layers"])
+    if last:
+        x = apply_norm(cfg, stage_params["final_norm"], x)
+        return logits_head(cfg, stage_params["embed"], x[:, -1:, :])
+    return x
 
 
 def _angles(cfg: ModelConfig, positions):

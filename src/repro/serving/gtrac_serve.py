@@ -46,10 +46,8 @@ from repro.core.registry import SeekerCache
 from repro.core.routing import ALGORITHMS
 from repro.core.sharding import make_registry
 from repro.core.types import HopReport
-from repro.distributed.pipeline import StagePartition, stage_forward
-from repro.models.common import apply_norm, embed_tokens, logits_head
-from repro.models.rope import positional_angles
-from repro.models.transformer import activation_dtype_params
+from repro.distributed.pipeline import StagePartition
+from repro.models.api import build_model
 from repro.obs.metrics import MetricsRegistry, percentiles
 from repro.obs.trace import (
     HOST_DOMAIN,
@@ -73,52 +71,33 @@ from repro.sync.gossip import make_sync_plane
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "first", "last"))
-def stage_step(cfg: ModelConfig, stage_params, tokens, x, *, first: bool,
-               last: bool):
-    """One stage's forward. The weights are arguments, never constants
-    of the program, so every middle stage shares one executable per
-    prefix length; ``served_stage_params`` gives them in the dtypes the
-    forward consumes. Stage 0 embeds ``tokens``; the last stage returns
-    the last position's logits instead of hidden states."""
-    B, S = tokens.shape
-    if first:
-        x = embed_tokens(cfg, stage_params["embed"], tokens)
-    pos = jnp.arange(S)[None, :].repeat(B, 0)
-    angles = (positional_angles(cfg, pos)
-              if cfg.pos_type in ("rope", "mrope") else None)
-    x = stage_forward(cfg, stage_params, x, angles)
-    if last:
-        x = apply_norm(cfg, stage_params["final_norm"], x)
-        return logits_head(cfg, stage_params["embed"], x[:, -1:, :])
-    return x
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "size"))
-def _stage_layers(cfg: ModelConfig, layers, start, *, size: int):
-    """Layers ``[start, start + size)`` of the stacks, cast as
-    ``activation_dtype_params`` says. Slice and cast fuse, so no
-    float32 copy of a cast slice is made."""
-    return activation_dtype_params(cfg, jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, start, size), layers))
+def stage_step(cfg: ModelConfig, stage_params, tokens, x, length=None, *,
+               first: bool, last: bool):
+    """One stage's forward, by the model family's ``stage_forward``. The
+    weights are arguments, never constants of the program, so stages of
+    one structure share one executable per prefix length (per bucket of
+    lengths where the family pads, ``Model.stage_bucket``);
+    ``served_stage_params`` gives them in the dtypes the forward
+    consumes. Stage 0 embeds ``tokens``; the last stage returns the last
+    position's logits instead of hidden states, at ``length - 1`` for
+    right-padded ``tokens``."""
+    kw = {} if length is None else {"length": length}
+    return build_model(cfg).stage_forward(stage_params, tokens, x,
+                                          first=first, last=last, **kw)
 
 
 def served_stage_params(cfg: ModelConfig, params,
                         partition: StagePartition) -> List[dict]:
-    """Each stage's weights as ``stage_step`` takes them: its slice of
-    the layer stacks, and the embedding and final norm that all stages
-    share, with the matmul weights and embedding tables in the
-    activation dtype (``models.transformer.activation_dtype_params``).
-    The forward consumes those only at that dtype; held at float32, each
-    call of a stage program would convert its whole weight stack again.
-    ``params`` stay the masters and are not changed."""
-    shared = activation_dtype_params(
-        cfg, {k: v for k, v in params.items() if k != "layers"})
-    stages = []
-    for i in range(partition.n_stages):
-        s, e = partition.segment(i)
-        stages.append({**shared, "layers": _stage_layers(
-            cfg, params["layers"], s, size=e - s)})
-    return stages
+    """Each stage's weights as ``stage_step`` takes them, by the model
+    family's ``served_stages``: its slice of the layers and what else
+    its layers read (the embedding and final norm; a hybrid model's
+    shared blocks), with the matmul weights and embedding tables in the
+    activation dtype. The forward consumes those only at that dtype;
+    held at float32, each call of a stage program would convert its
+    whole weight stack again. ``params`` stay the masters and are not
+    changed."""
+    return build_model(cfg).served_stages(
+        params, [partition.segment(i) for i in range(partition.n_stages)])
 
 
 def weight_bytes(stages: Sequence[dict]) -> Dict[str, int]:
@@ -132,16 +111,34 @@ def weight_bytes(stages: Sequence[dict]) -> Dict[str, int]:
     return out
 
 
+def shared_block_bytes(stages: Sequence[dict]) -> int:
+    """Bytes of the shared blocks that the stages hold, counted once per
+    stage that holds them: what sharing a block's weights across the
+    depth costs once the depth is split into stages on separate peers."""
+    total = 0
+    for sp in stages:
+        held = {id(a): a for h in sp.get("hybrid", {}).values()
+                for a in jax.tree.leaves(h["block"])}
+        total += sum(a.nbytes for a in held.values())
+    return total
+
+
 def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition,
                    obs: Optional[MetricsRegistry] = None):
     """One hop fn per stage over a ``(tokens, x)`` payload, on the
-    weights of ``served_stage_params``, made once, here. ``obs`` gets
-    their bytes per dtype as gauges ``stage/weight_bytes_<dtype>``."""
+    weights of ``served_stage_params``, made once, here. Where the family
+    has a ``stage_bucket``, each hop right-pads ``tokens`` (on the host)
+    to a multiple of it, so ``x`` between stages holds the padded
+    positions; the payload's ``tokens`` stay as they came. ``obs`` gets
+    the weights' bytes per dtype as gauges ``stage/weight_bytes_<dtype>``,
+    and the shared blocks' as ``stage/shared_block_bytes``."""
     stages = served_stage_params(cfg, params, partition)
     if obs is not None:
         for name, n in weight_bytes(stages).items():
             obs.gauge(f"stage/weight_bytes_{name}").set(n)
+        obs.gauge("stage/shared_block_bytes").set(shared_block_bytes(stages))
     n = len(stages)
+    bucket = build_model(cfg).stage_bucket
 
     def stage_fn(i: int):
         sp = stages[i]
@@ -149,7 +146,13 @@ def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition,
 
         def fn(payload):
             tokens, x = payload                     # x is None at stage 0
-            return tokens, stage_step(cfg, sp, tokens, x, **flags)
+            if bucket is None:
+                return tokens, stage_step(cfg, sp, tokens, x, **flags)
+            B, S = tokens.shape
+            padded = np.zeros((B, -(-S // bucket) * bucket), np.int32)
+            padded[:, :S] = np.asarray(tokens)
+            return tokens, stage_step(cfg, sp, padded, x, np.int32(S),
+                                      **flags)
 
         return fn
 

@@ -116,20 +116,48 @@ def test_wkv6_nonzero_initial_state():
     np.testing.assert_allclose(s, sr, atol=5e-4)
 
 
-@pytest.mark.parametrize("B,S,H,P,N,chunk", [
-    (2, 64, 2, 16, 8, 16),
-    (1, 128, 4, 32, 16, 32),
+@pytest.mark.parametrize("B,S,H,P,N,G,chunk", [
+    (2, 64, 2, 16, 8, 1, 16),
+    (1, 128, 4, 32, 16, 1, 32),
+    (1, 64, 4, 16, 8, 2, 16),
+    (2, 96, 8, 16, 8, 2, 32),
 ])
-def test_ssd_chunked(B, S, H, P, N, chunk):
-    ks = jax.random.split(KEY, 5)
+def test_ssd_chunked(B, S, H, P, N, G, chunk):
+    """The Pallas scan (interpret mode), the jnp chunked scan and the
+    per-position recurrence agree, with B and C in G groups and a
+    carried initial state."""
+    from repro.models.mamba2 import ssd_chunked as ssd_jnp
+    ks = jax.random.split(KEY, 6)
     x = jax.random.normal(ks[0], (B, S, H, P))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
     la = -jnp.exp(jax.random.normal(ks[2], (B, S, H)) - 1.0) * dt
-    Bm = jax.random.normal(ks[3], (B, S, N))
-    Cm = jax.random.normal(ks[4], (B, S, N))
+    Bm = jax.random.normal(ks[3], (B, S, G, N))
+    Cm = jax.random.normal(ks[4], (B, S, G, N))
+    h0 = 0.1 * jax.random.normal(ks[5], (B, H, N, P))
+    dx = x * dt[..., None]
+    y, h = ssd_chunked(dx, la, Bm, Cm, h0, chunk=chunk, interpret=True)
+    yr, hr = ref.ssd_ref(dx, la, Bm, Cm, h0)
+    yj, hj = ssd_jnp(x, dt, la, Bm, Cm, h0, chunk)
+    for got in ((y, h), (yj, hj)):
+        np.testing.assert_allclose(got[0], yr, atol=5e-4)
+        np.testing.assert_allclose(got[1], hr, atol=5e-4)
+
+
+def test_ssd_pads_to_the_chunk():
+    """``mamba2.ssd`` over a length that is no multiple of the chunk
+    equals the recurrence: padded steps leave the state as it is."""
+    from repro.models.mamba2 import ssd
+    B, S, H, P, N, G = 1, 21, 4, 8, 8, 2
+    ks = jax.random.split(KEY, 5)
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    la = -jnp.exp(jax.random.normal(ks[2], (B, S, H))) * dt
+    Bm = jax.random.normal(ks[3], (B, S, G, N))
+    Cm = jax.random.normal(ks[4], (B, S, G, N))
     h0 = jnp.zeros((B, H, N, P))
-    y, h = ssd_chunked(x, dt, la, Bm, Cm, h0, chunk=chunk, interpret=True)
-    yr, hr = ref.ssd_ref(x, dt, la, Bm, Cm, h0)
+    y, h = ssd(x, dt, la, Bm, Cm, h0, chunk=8)
+    yr, hr = ref.ssd_ref(x * dt[..., None], la, Bm, Cm, h0)
+    assert y.shape == x.shape
     np.testing.assert_allclose(y, yr, atol=5e-4)
     np.testing.assert_allclose(h, hr, atol=5e-4)
 

@@ -79,11 +79,13 @@ def test_tropical_route_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _stage_program(cfg, stage, one_chip, first, last, S=250):
+def _stage_program(cfg, stage, one_chip, first, last, S=250, padded=False):
     stage = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), stage)
     tokens = _spec((1, S), jnp.int32, one_chip)
     x = None if first else _spec((1, S, cfg.d_model), jnp.bfloat16, one_chip)
-    lowered = stage_step.lower(cfg, stage, tokens, x, first=first, last=last)
+    length = _spec((), jnp.int32, one_chip) if padded else None
+    lowered = stage_step.lower(cfg, stage, tokens, x, length, first=first,
+                               last=last)
     assert len(lowered.as_text()) < MAX_PROGRAM_TEXT
     return lowered.compile()
 
@@ -133,3 +135,54 @@ def test_gpt2_large_stage_compiles(one_chip, first, last, layers):
             <= 0.55 * f32.memory_analysis().argument_size_in_bytes)
     if layers == 9:
         assert mem.temp_size_in_bytes < 10 * 1024 ** 2
+
+
+def _zamba2_7b_cut():
+    """Zamba2-7B at its published widths, layers 0-11: hybrid at 6, 11."""
+    import dataclasses
+    return dataclasses.replace(get_config("zamba2-7b"), num_layers=12,
+                               hybrid_layer_ids=(6, 11))
+
+
+@pytest.mark.parametrize("i", [0, 1, 2], ids=["first", "middle", "last"])
+def test_zamba2_7b_stage_compiles(one_chip, monkeypatch, i):
+    """A full-width Zamba2-7B stage of 4 layers (stage 1 holds hybrid
+    layer 6 and shared block 0, stage 2 layer 11 and block 1), built as
+    the server builds it, fits the chip, takes its bf16 weights as
+    arguments (no float32 stack, no weights as constants) and runs its
+    scan as the Pallas ``ssd_chunk`` kernel, once per program."""
+    from repro.kernels import ops
+    from repro.models import zamba2
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = _zamba2_7b_cut()
+    shapes = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
+    stages = jax.eval_shape(lambda p: served_stage_params(
+        cfg, p, StagePartition.uniform(12, 4)), shapes)
+    assert [sorted(s["hybrid"]) for s in stages] == [[], [2], [3]]
+    # 250 positions, right-padded to the served bucket of 32
+    S = -(-250 // zamba2.STAGE_BUCKET) * zamba2.STAGE_BUCKET
+    compiled = _stage_program(cfg, stages[i], one_chip, i == 0, i == 2, S=S,
+                              padded=True)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert total < V5E_HBM_BYTES
+    bf16 = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(stages[i]))
+    # the weights as held, and the (1, 256, d) bf16 activations
+    assert mem.argument_size_in_bytes < bf16 + (4 << 20)
+    text = _unfused_text(compiled)
+    d, w = cfg.d_model, compiled.as_text()
+    assert f"f32[4,{d},14704]" not in text and f"f32[{2 * d},7168]" not in text
+    assert len(re.findall(r"%ssd_chunk[.\d]* = .*custom-call", w)) == 1
+
+
+def test_ssd_chunk_compiles(one_chip):
+    """The Pallas SSD scan at Zamba2-7B's shapes: 112 heads of 64, state
+    64, two groups, chunks of 256, over two chunks."""
+    from repro.kernels.ssd_chunk import ssd_chunked
+    B, S, H, P, N, G = 1, 512, 112, 64, 64, 2
+    f32 = lambda s: _spec(s, jnp.float32, one_chip)
+    compiled = ssd_chunked.lower(f32((B, S, H, P)), f32((B, S, H)),
+                                 f32((B, S, G, N)), f32((B, S, G, N)),
+                                 f32((B, H, N, P)), chunk=256).compile()
+    assert re.search(r"%ssd_chunk[.\d]* = .*custom-call", compiled.as_text())
