@@ -86,6 +86,14 @@ def stage_step(cfg: ModelConfig, stage_params, tokens, x, length=None, *,
                                           first=first, last=last, **kw)
 
 
+@jax.jit
+def greedy_token(logits):
+    """The greedy next token of each row of the last stage's logits
+    ``(B, 1, V)``, as ``(B,)`` int32 on the device: one program for
+    every prefix length, and nothing read by the host."""
+    return jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)
+
+
 def served_stage_params(cfg: ModelConfig, params,
                         partition: StagePartition) -> List[dict]:
     """Each stage's weights as ``stage_step`` takes them, by the model
@@ -127,9 +135,10 @@ def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition,
                    obs: Optional[MetricsRegistry] = None):
     """One hop fn per stage over a ``(tokens, x)`` payload, on the
     weights of ``served_stage_params``, made once, here. Where the family
-    has a ``stage_bucket``, each hop right-pads ``tokens`` (on the host)
-    to a multiple of it, so ``x`` between stages holds the padded
-    positions; the payload's ``tokens`` stay as they came. ``obs`` gets
+    has a ``stage_bucket``, each hop right-pads ``tokens`` to a multiple
+    of it, so ``x`` between stages holds the padded positions; the
+    payload's ``tokens`` stay as they came. ``run_queue`` gives them as
+    host ids, so the pad reads nothing from the device. ``obs`` gets
     the weights' bytes per dtype as gauges ``stage/weight_bytes_<dtype>``,
     and the shared blocks' as ``stage/shared_block_bytes``."""
     stages = served_stage_params(cfg, params, partition)
@@ -150,7 +159,7 @@ def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition,
                 return tokens, stage_step(cfg, sp, tokens, x, **flags)
             B, S = tokens.shape
             padded = np.zeros((B, -(-S // bucket) * bucket), np.int32)
-            padded[:, :S] = np.asarray(tokens)
+            padded[:, :S] = tokens
             return tokens, stage_step(cfg, sp, padded, x, np.int32(S),
                                       **flags)
 
@@ -329,7 +338,6 @@ class RoutedRequest(Request):
     """Engine admission request + per-stream routed serving state."""
 
     metrics: ServeMetrics = field(default_factory=ServeMetrics)
-    tokens: Optional[jnp.ndarray] = None    # (1, S) running token tensor
     # ChainExecutor, or HedgedChainExecutor when cfg.hedge_enabled
     executor: Optional[object] = None
     # disaggregated prefill progress: prompt tokens prefilled so far, the
@@ -338,6 +346,12 @@ class RoutedRequest(Request):
     prefill_pos: int = 0
     busy_until: float = 0.0
     _pending_tok: int = 0
+
+    def ids(self) -> np.ndarray:
+        """The stream's token ids on the host, ``(1, S)`` int32: the
+        prompt, then every token emitted so far."""
+        return np.concatenate(
+            [self.prompt, np.asarray(self.output, np.int32)])[None, :]
 
 
 class GTRACPipelineServer:
@@ -359,6 +373,10 @@ class GTRACPipelineServer:
         # the telemetry plane (below) starts with the stage weights'
         # bytes per dtype
         self.obs = MetricsRegistry()
+        # run_queue's device-to-host reads of token ids (one per window
+        # that picked any) and the ids they carried
+        self._token_reads = self.obs.counter("serve/token_reads")
+        self._tokens_read = self.obs.counter("serve/tokens_read")
         self.stage_fns = make_stage_fns(cfg, params, self.partition,
                                         self.obs)
         rng = np.random.default_rng(seed)
@@ -666,7 +684,6 @@ class GTRACPipelineServer:
         rid = (self.admission.next_request_id()
                if spec.request_id is None else spec.request_id)
         req = RoutedRequest.from_spec(spec, rid)
-        req.tokens = jnp.asarray(req.prompt, jnp.int32)[None, :]
         hop = self._hop_fn(rid, kv_tracked=True)
         # hedged window serving: behind cfg.hedge_enabled each stream runs
         # the hedging executor (fires a backup hop when the primary exceeds
@@ -685,8 +702,6 @@ class GTRACPipelineServer:
         """Append one generated token and stamp its emission time."""
         ht = self.host_tracer
         with (ht.span("emit") if ht.enabled else NOOP_SPAN):
-            req.tokens = jnp.concatenate(
-                [req.tokens, jnp.full((1, 1), int(tok), jnp.int32)], axis=1)
             req.output.append(int(tok))
             req.metrics.tokens += 1
             req.metrics.emit_ms.append(t_emit * 1e3)
@@ -741,6 +756,31 @@ class GTRACPipelineServer:
                 req.metrics.hedges_fired = stats.hedges_fired
                 req.metrics.hedges_won = stats.hedges_won
 
+    def _pick(self, logits, ht) -> jax.Array:
+        """The greedy token of one stream's chain, picked on the device;
+        its copy to the host starts, and nothing waits for it."""
+        with (ht.span("token_pick") if ht.enabled else NOOP_SPAN):
+            tok = greedy_token(logits)
+            tok.copy_to_host_async()
+        return tok
+
+    def _read_and_emit(self, picks, now: float, ht) -> None:
+        """Read every token the window picked, in one device-to-host read,
+        then emit the decode streams' tokens in the window's stream order
+        at ``now`` plus their chains' latency. A final prefill chunk's
+        first token is kept for its stream's promotion."""
+        with (ht.span("token_sync") if ht.enabled else NOOP_SPAN):
+            toks = jax.device_get([tok for _, tok, _ in picks])
+        self._token_reads.inc()
+        self._tokens_read.inc(len(picks))
+        for (req, _, ms), tok in zip(picks, toks):
+            tok = int(tok[0])
+            if ms is None:
+                req._pending_tok = tok
+            else:
+                req.metrics.token_latency_ms.append(ms)
+                self._emit_token(req, tok, now + ms / 1e3)
+
     def run_queue(self) -> List[RoutedRequest]:
         """Serve every queued stream to completion under continuous
         window batching. Each window: one registry sweep (vectorized TTL
@@ -764,9 +804,13 @@ class GTRACPipelineServer:
         ``run_queue`` span and each window a ``window`` span holding its
         phases: ``admit``, ``sync_view``, ``route`` (the DP itself is
         ``route.dp``), then per stream ``execute`` (one ``hop.dispatch``
-        per stage call), ``trust_fold``, ``kv``, ``token_sync`` (the
-        device-to-host read of a token) and ``emit``, and last
-        ``finish``."""
+        per stage call), ``trust_fold``, ``kv`` and ``token_pick`` (the
+        greedy token picked on the device, its copy to the host started),
+        then ``token_sync`` (the window's one device-to-host read of
+        every token it picked), ``emit`` per stream that emitted, and
+        last ``finish``. No token is read before ``token_sync``: the
+        payloads carry host ids, and nothing that routes or folds trust
+        reads a token's value."""
         ht = self.host_tracer
         with (ht.span("run_queue") if ht.enabled else NOOP_SPAN):
             served = self._serve_windows(ht)
@@ -818,7 +862,7 @@ class GTRACPipelineServer:
                 # completion) and hand the warm stream to the decode pool
                 waiting: List[RoutedRequest] = []
                 for req in prefill:
-                    if req.prefill_pos >= int(req.tokens.shape[1]) \
+                    if req.prefill_pos >= len(req.prompt) \
                             and req.busy_until <= now:
                         self._emit_token(req, req._pending_tok,
                                          req.busy_until)
@@ -844,7 +888,7 @@ class GTRACPipelineServer:
                     if req.busy_until > now:
                         continue               # chunk still in flight
                     c = min(gcfg.prefill_chunk_tokens,
-                            int(req.tokens.shape[1]) - req.prefill_pos,
+                            len(req.prompt) - req.prefill_pos,
                             self.admission.max_batch)
                     if budget is not None:
                         c = min(c, budget)
@@ -879,6 +923,11 @@ class GTRACPipelineServer:
                         req.metrics.stale_rounds_max = max(
                             req.metrics.stale_rounds_max, stale_rounds)
                     plans = self.router.route_window(table)  # ONE DP
+                # tokens picked on the device this window, read once at
+                # its end: (stream, token, chain latency in ms; None for
+                # a final prefill chunk's first token)
+                picks: List[Tuple[RoutedRequest, jax.Array,
+                                  Optional[float]]] = []
                 # -- prefill chunk launches (asynchronous: charge
                 #    busy_until, the decode window below does not wait
                 #    for them) --------------------------------------------
@@ -894,7 +943,8 @@ class GTRACPipelineServer:
                     with (ht.span("execute") if hon else NOOP_SPAN):
                         report, out = req.executor.execute(
                             plan.chain_ids(0), table,
-                            payload=(req.tokens[:, :end], None), plan=plan)
+                            payload=(req.prompt[None, :end], None),
+                            plan=plan)
                     self._apply_report(req, report)
                     if traced:
                         psp = self._req_spans.get(req.request_id)
@@ -921,11 +971,9 @@ class GTRACPipelineServer:
                     req.metrics.prefill_tokens += c
                     req.prefill_pos = end
                     req.busy_until = now + report.total_latency_ms / 1e3
-                    if end == int(req.tokens.shape[1]):
+                    if end == len(req.prompt):
                         _, logits = out        # final chunk: first token
-                        with (ht.span("token_sync") if hon else NOOP_SPAN):
-                            req._pending_tok = int(
-                                jnp.argmax(logits[:, -1, :], -1)[0])
+                        picks.append((req, self._pick(logits, ht), None))
                 # -- decode window: one token per stream ----------------
                 window_ms = 0.0
                 w_spans: List[Tuple[object, float]] = []
@@ -935,11 +983,12 @@ class GTRACPipelineServer:
                         req.metrics.infeasible += 1
                         req.done = True
                         continue
-                    prefix = int(req.tokens.shape[1])
+                    ids = req.ids()
+                    prefix = ids.shape[1]
                     with (ht.span("execute") if hon else NOOP_SPAN):
                         report, payload = req.executor.execute(
                             plan.chain_ids(0), table,
-                            payload=(req.tokens, None), plan=plan)
+                            payload=(ids, None), plan=plan)
                     self._apply_report(req, report)
                     window_ms = max(window_ms, report.total_latency_ms)
                     if traced:
@@ -970,12 +1019,10 @@ class GTRACPipelineServer:
                                 req.metrics.kv_cold_steps += 1
                         self.kv.record(req.request_id, report.chain, prefix)
                     _, logits = payload
-                    with (ht.span("token_sync") if hon else NOOP_SPAN):
-                        tok = int(jnp.argmax(logits[:, -1, :], -1)[0])
-                    req.metrics.token_latency_ms.append(
-                        report.total_latency_ms)
-                    self._emit_token(req, tok,
-                                     now + report.total_latency_ms / 1e3)
+                    picks.append((req, self._pick(logits, ht),
+                                  report.total_latency_ms))
+                if picks:
+                    self._read_and_emit(picks, now, ht)
                 # decode streams run concurrently: the clock advances by
                 # the window's max decode latency; a pure-prefill window
                 # advances to its earliest chunk completion instead

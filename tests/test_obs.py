@@ -692,7 +692,8 @@ class TestHostTracer:
         spans = _program_spans(tr.sink)
         hops = (("hop.dispatch", ()), ("hop.dispatch", ()))
         tail = (("execute", hops), ("trust_fold", ()), ("kv", ()),
-                ("token_sync", ()), ("emit", ()), ("finish", ()))
+                ("token_pick", ()), ("token_sync", ()), ("emit", ()),
+                ("finish", ()))
         first = (("admit", ()), ("sync_view", ()),
                  ("route", (("route.dp", ()),))) + tail
         # the second window routes on the unchanged snapshot and floor:
@@ -711,8 +712,10 @@ class TestHostTracer:
 
     def test_one_dispatch_per_hop_and_one_sync_per_token(self, tiny_model):
         """Across chunked prefill, failing peers and repairs: a stage
-        call for every hop that ran, a device-to-host read for every
-        token the streams emitted."""
+        call for every hop that ran, a token picked on the device for
+        every token the streams emitted, and one device-to-host read
+        (``token_sync``) per window that picked any, after its last
+        pick; the server's counters agree."""
         from repro.serving.api import SubmitSpec
         srv = _traced_server(tiny_model, disaggregate=True,
                              prefill_chunk_tokens=4)
@@ -731,11 +734,22 @@ class TestHostTracer:
         dispatched = [sp for sp in host if sp.name == "hop.dispatch"]
         assert ran and failed, "the fleet should both serve and fail"
         assert len(dispatched) == len(ran)
+        picks = [sp for sp in host if sp.name == "token_pick"]
         syncs = [sp for sp in host if sp.name == "token_sync"]
-        assert len(syncs) == sum(r.metrics.tokens for r in done) > 0
+        emitted = sum(r.metrics.tokens for r in done)
+        assert len(picks) == emitted > len(syncs) > 0
         by_id = {sp.span_id: sp for sp in host}
         for sp in dispatched:
             assert by_id[sp.parent_id].name == "execute"
+        for window in (sp for sp in host if sp.name == "window"):
+            kids = [sp for sp in host if sp.parent_id == window.span_id]
+            wpicks = [sp for sp in kids if sp.name == "token_pick"]
+            wsyncs = [sp for sp in kids if sp.name == "token_sync"]
+            assert len(wsyncs) == (1 if wpicks else 0)
+            assert all(p.t1 <= s.t0 for p in wpicks for s in wsyncs)
+        snap = srv.obs.snapshot()
+        assert snap["serve/token_reads"] == len(syncs)
+        assert snap["serve/tokens_read"] == emitted
 
     def test_gc_and_compile_spans_while_set(self):
         import jax
@@ -849,7 +863,8 @@ class TestHostTracer:
             assert q0 <= s and e <= q1
         assert len(events["gtrac.hop.dispatch"]) == 4
         assert len(events["gtrac.token_sync"]) == 2
-        for name in ("admit", "route", "execute", "token_sync", "emit"):
+        for name in ("admit", "route", "execute", "token_pick",
+                     "token_sync", "emit"):
             assert inside(f"gtrac.{name}", "gtrac.window")
         assert inside("gtrac.hop.dispatch", "gtrac.execute")
         assert inside("gtrac.route.dp", "gtrac.route")

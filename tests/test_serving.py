@@ -269,6 +269,182 @@ class TestGTRACServer:
                 assert list(out) == want
 
 
+# run_queue's sim clock per stream, as served by the loop that read each
+# token from the device before the next stream's hops (commit f9738a3):
+# (prompt length, emit_ms, token_latency_ms). Same fleet seed, prompts and
+# budgets as ``_window_run``; reading tokens once per window changes no draw.
+PARENT_SIM_STAMPS = {
+    "gpt2": [
+        (5, [1094.5464360926412, 10324.852815965578, 11540.893123513491,
+             11901.17570316153, 12211.139511309648, 12498.731942188731],
+         [1094.5464360926412, 927.6921711092938, 289.3243264220334,
+          319.28314773051864, 309.9638081481178, 287.5924308790835]),
+        (9, [1968.836659917907, 10788.073790788163, 11559.46516427315],
+         [1968.836659917907, 1390.913145931879, 307.89636718169027]),
+        (13, [9397.160644856283, 11251.568797091459, 11543.589142508785,
+              11899.828534310565, 12200.317154108008],
+         [9397.160644856283, 1854.4081522351744, 292.02034541732576,
+          317.9359788795528, 299.1414509464771]),
+        (7, [1747.2404751106624, 10353.166094819997, 11581.892555431014,
+             11885.523446460531],
+         [1747.2404751106624, 956.0054499637145, 330.32375833955393,
+          303.6308910295187]),
+    ],
+    "zamba2": [
+        (29, [10137.56599104846, 13630.67240292372, 14018.979342220291,
+              14480.834471080472, 14875.172762124592],
+         [10137.56599104846, 426.52016985539046, 388.30693929657207,
+          412.0663288757936, 394.33829104411916]),
+        (31, [11171.552579874744, 13580.622740513452, 14068.768142204679],
+         [11171.552579874744, 376.47050744512273, 438.0957392809588]),
+        (40, [13204.15223306833, 13604.706114354978, 14054.282595226337,
+              14466.53010129137],
+         [13204.15223306833, 400.55388128664754, 423.6101923026181,
+          397.7619590866899]),
+    ],
+}
+
+
+def _window_model(family):
+    """A test-width model of ``family`` with its prompts and budgets. The
+    GPT-2 layers are scaled up so greedy decoding does not just repeat a
+    token; Zamba2's prompts straddle its 32-position stage bucket."""
+    if family == "gpt2":
+        cfg = get_config("gpt2-large").reduced(num_layers=4, vocab_size=128,
+                                               remat=False)
+        params = build_model(cfg).init(KEY)
+        params = {**params, "layers": jax.tree.map(lambda a: a * 8,
+                                                   params["layers"])}
+        lens, budgets = (5, 9, 13, 7), (6, 3, 5, 4)
+    else:
+        cfg = get_config("zamba2-7b").reduced(num_layers=6,
+                                              hybrid_layer_ids=(2, 5))
+        params = build_model(cfg).init(KEY)
+        lens, budgets = (29, 31, 40), (5, 3, 4)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in lens]
+    return cfg, params, prompts, budgets
+
+
+def _window_run(cfg, params, prompts, budgets, on_window=None):
+    """``run_queue`` over streams of different prompt and output lengths
+    on a fleet that repairs some hops; returns the server and streams."""
+    srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
+                              replicas={"honeypot": 1, "turtle": 1,
+                                        "golden": 2}, seed=3)
+    if on_window is not None:
+        on_window(srv)
+    reqs = [srv.submit(SubmitSpec(prompt=p, max_new_tokens=n))
+            for p, n in zip(prompts, budgets)]
+    srv.run_queue()
+    return srv, reqs
+
+
+@pytest.mark.parametrize("family", ["gpt2", "zamba2"])
+class TestOneTokenReadPerWindow:
+    def test_serves_generates_tokens_and_the_parents_sim_clock(self,
+                                                                family):
+        cfg, params, prompts, budgets = _window_model(family)
+        _, reqs = _window_run(cfg, params, prompts, budgets)
+        ref = GTRACPipelineServer(cfg, params, layers_per_stage=2,
+                                  replicas={"golden": 2}, seed=0)
+        want = PARENT_SIM_STAMPS[family]
+        assert len(reqs) == len(want)
+        for req, (n, emit_ms, latency_ms) in zip(reqs, want):
+            out, met = ref.generate(req.prompt, max_new_tokens=len(emit_ms))
+            assert len(req.prompt) == n
+            assert req.output == [int(t) for t in out]
+            assert met.tokens == len(req.output) == req.max_new_tokens
+            assert req.metrics.emit_ms == pytest.approx(emit_ms, rel=1e-12)
+            assert req.metrics.token_latency_ms == pytest.approx(
+                latency_ms, rel=1e-12)
+
+    def test_one_read_per_window_after_its_last_execute(self, family,
+                                                        monkeypatch):
+        """Token ids cross to the host once per decode window, after every
+        chain of the window was dispatched; ``_emit_token`` sees Python
+        ints; no hop reads a device array (the padded family pads the
+        host ids)."""
+        from jax._src.array import ArrayImpl
+
+        cfg, params, prompts, budgets = _window_model(family)
+        log = []                            # "window" | "execute" | "emit"
+        in_hop, hop_reads = [False], []
+        for name in ("__array__", "__buffer__"):   # numpy's two ways in
+            def counted(self, *a, _read=getattr(ArrayImpl, name), **kw):
+                if in_hop[0]:
+                    hop_reads.append(self.shape)
+                return _read(self, *a, **kw)
+
+            monkeypatch.setattr(ArrayImpl, name, counted)
+        value = ArrayImpl._value              # int(), .item(), ...
+        monkeypatch.setattr(ArrayImpl, "_value", property(
+            lambda self: counted(self, _read=lambda a: value.fget(a))))
+
+        def hooks(srv):
+            nxt = srv.admission.next_window
+
+            def next_window(*a, **kw):
+                log.append("window")
+                return nxt(*a, **kw)
+
+            srv.admission.next_window = next_window
+            emit = srv._emit_token
+
+            def emit_token(req, tok, t_emit):
+                assert type(tok) is int
+                log.append("emit")
+                emit(req, tok, t_emit)
+
+            srv._emit_token = emit_token
+
+            def hop_of(fn):
+                def hop(payload):
+                    in_hop[0] = True
+                    try:
+                        return fn(payload)
+                    finally:
+                        in_hop[0] = False
+                return hop
+
+            srv.stage_fns = [hop_of(fn) for fn in srv.stage_fns]
+            submit = srv.submit
+
+            def submit_logged(spec):
+                req = submit(spec)
+                run = req.executor.execute
+
+                def execute(*a, **kw):
+                    log.append("execute")
+                    return run(*a, **kw)
+
+                req.executor.execute = execute
+                return req
+
+            srv.submit = submit_logged
+
+        srv, reqs = _window_run(cfg, params, prompts, budgets, hooks)
+        assert hop_reads == []
+        windows, cur = [], None
+        for ev in log:
+            if ev == "window":
+                cur = []
+                windows.append(cur)
+            else:
+                cur.append(ev)
+        emitting = [w for w in windows if "emit" in w]
+        for w in emitting:              # every emit after the last execute
+            first_emit = w.index("emit")
+            assert "execute" not in w[first_emit:]
+        snap = srv.obs.snapshot()
+        tokens = sum(len(r.output) for r in reqs)
+        assert snap["serve/token_reads"] == len(emitting) < tokens
+        assert snap["serve/tokens_read"] == tokens == log.count("emit")
+        # a full window reads as many tokens as it has streams
+        assert max(w.count("emit") for w in emitting) == len(reqs)
+
+
 class TestSubmitSpecAPI:
     def test_spec_validation(self):
         with pytest.raises(ValueError):

@@ -186,3 +186,36 @@ def test_ssd_chunk_compiles(one_chip):
                                  f32((B, S, G, N)), f32((B, S, G, N)),
                                  f32((B, H, N, P)), chunk=256).compile()
     assert re.search(r"%ssd_chunk[.\d]* = .*custom-call", compiled.as_text())
+
+
+@pytest.mark.parametrize("family", ["gpt2", "zamba2"])
+def test_run_queue_reads_tokens_only_at_window_end(family, monkeypatch):
+    """On the chip, ``run_queue``'s windows at test widths under a guard
+    that refuses every implicit device-to-host transfer: hops pad and
+    dispatch, tokens are picked on the device, and the one read per
+    window is the explicit ``jax.device_get``. The router's device DP
+    reads its plans back, so its call alone is let through. The CPU
+    backend never fires the guard, so this runs on a TPU only."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("the device-to-host transfer guard fires on a TPU only")
+    from test_serving import _window_model, _window_run
+
+    from repro.kernels import ops
+    # the Pallas scan's blocks are sized for published widths; the test
+    # widths take the scan's jnp form
+    monkeypatch.setattr(ops, "on_tpu", lambda: False)
+
+    def allow_route(srv):
+        route = srv.router.route_window
+
+        def route_window(table):
+            with jax.transfer_guard_device_to_host("allow"):
+                return route(table)
+
+        srv.router.route_window = route_window
+
+    cfg, params, prompts, budgets = _window_model(family)
+    with jax.transfer_guard_device_to_host("disallow"):
+        srv, reqs = _window_run(cfg, params, prompts, budgets, allow_route)
+    assert [len(r.output) for r in reqs] == list(budgets)
+    assert srv.obs.snapshot()["serve/tokens_read"] == sum(budgets)
