@@ -107,8 +107,8 @@ def bench_routing_overhead(cfg: GTRACConfig, trials: int, seed: int,
 
 
 def bench_end_to_end(seed: int = 0):
-    """Tokens/sec (wall clock) of the routed sim pipeline: per-token
-    ``generate`` loop vs window-batched ``run_queue``, same streams."""
+    """Tokens/sec (wall clock) of the routed sim pipeline's
+    window-batched ``run_queue``."""
     import jax
     from repro.configs import get_config
     from repro.models.api import build_model
@@ -121,48 +121,27 @@ def bench_end_to_end(seed: int = 0):
     streams, tokens = 4, 6
     prompt = np.arange(1, 9)
 
-    def serve(windowed: bool, reps: int = 3) -> float:
+    def serve(reps: int = 3) -> float:
         # warm-up compile pass, then best-of-reps on fresh servers (the
         # 24-token window is jax-dispatch dominated, so a single timed
         # shot scatters ~10% run to run)
-        srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
-                                  replicas={"golden": 2}, seed=seed)
-        if windowed:
+        best = 0.0
+        for rep in range(reps + 1):
+            srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
+                                      replicas={"golden": 2}, seed=seed)
             for _ in range(streams):
                 srv.submit(SubmitSpec(prompt=prompt, max_new_tokens=tokens))
-            srv.run_queue()
-        else:
-            srv.generate(prompt, max_new_tokens=tokens)
-        best = 0.0
-        for _ in range(reps):
-            srv2 = GTRACPipelineServer(cfg, params, layers_per_stage=2,
-                                       replicas={"golden": 2}, seed=seed)
-            if windowed:
-                for _ in range(streams):
-                    srv2.submit(SubmitSpec(prompt=prompt,
-                                           max_new_tokens=tokens))
-                t0 = time.perf_counter()
-                done = srv2.run_queue()
-                dt = time.perf_counter() - t0
-                n = sum(r.metrics.tokens for r in done)
-            else:
-                t0 = time.perf_counter()
-                n = 0
-                for rid in range(streams):
-                    _, met = srv2.generate(prompt, max_new_tokens=tokens,
-                                           request_id=rid)
-                    n += met.tokens
-                dt = time.perf_counter() - t0
-            best = max(best, n / dt)
+            t0 = time.perf_counter()
+            done = srv.run_queue()
+            dt = time.perf_counter() - t0
+            if rep:
+                best = max(best, sum(r.metrics.tokens for r in done) / dt)
         return best
 
-    tps_loop = serve(windowed=False)
-    tps_win = serve(windowed=True)
-    emit("serving/e2e/tokens_per_s/per_token", 1e6 / tps_loop,
-         f"{tps_loop:.1f}tok_per_s")
+    tps_win = serve()
     emit("serving/e2e/tokens_per_s/windowed", 1e6 / tps_win,
          f"{tps_win:.1f}tok_per_s")
-    return {"per_token": round(tps_loop, 2), "windowed": round(tps_win, 2)}
+    return {"windowed": round(tps_win, 2)}
 
 
 def bench_trace_overhead(seed: int = 0, quick: bool = False):

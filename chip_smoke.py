@@ -3,7 +3,7 @@
     python chip_smoke.py [--seed N]
 
 Run from the repository root; the script puts ``src`` on the path itself.
-It drives the same objects as ``python -m repro.launch.serve --windowed``
+It drives the same objects as ``python -m repro.launch.serve``
 (``build_model``, ``GTRACPipelineServer``, ``SubmitSpec``, ``run_queue``)
 at GPT-2 Large's published width and depth (36 layers, d_model 1280, vocab
 50,257, float32 parameters from ``--seed``), in five phases:

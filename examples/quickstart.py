@@ -48,7 +48,7 @@ def main():
     srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
                               replicas={"honeypot": 2, "golden": 2,
                                         "turtle": 1},
-                              algorithm="gtrac", seed=0)
+                              seed=0)
     for rid in range(3):
         out, met = srv.generate(np.arange(1, 9), max_new_tokens=8,
                                 request_id=rid)

@@ -1,11 +1,12 @@
 """END-TO-END DRIVER: serve a small model with batched requests through the
-full G-TRAC stack, comparing routing policies under adversarial peers.
+full G-TRAC stack under adversarial peers.
 
 This is the paper's system running for real: the model is layer-sharded
 across simulated edge peers (honeypot / turtle / golden profiles), every
-token's chain is routed from the seeker's gossip-synced cached view, hops
-execute REAL jitted stage computations, failures trigger Bounded One-Shot
-Repair, and the Anchor learns trust from execution reports.
+token window's chains are routed from the seeker's cached view in one
+batched solve, hops execute REAL jitted stage computations, failures
+trigger Bounded One-Shot Repair, and the Anchor learns trust from
+execution reports.
 
     PYTHONPATH=src python examples/serve_gtrac.py [--requests 12] [--tokens 12]
 """
@@ -16,6 +17,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models.api import build_model
+from repro.serving.api import SubmitSpec
 from repro.serving.gtrac_serve import GTRACPipelineServer
 
 
@@ -37,33 +39,25 @@ def main():
     print(f"model: {cfg.num_layers} layers, "
           f"{cfg.num_layers // args.layers_per_stage} pipeline stages, "
           f"peers/stage: {sum(replicas.values())} {replicas}")
-    print(f"{'policy':8s} {'SSR':>6s} {'tok/s-lat':>10s} {'repairs':>8s} "
-          f"{'failures':>9s}")
+    srv = GTRACPipelineServer(cfg, params,
+                              layers_per_stage=args.layers_per_stage,
+                              replicas=replicas, seed=args.seed)
+    for _ in range(args.requests):
+        srv.submit(SubmitSpec(prompt=rng.integers(1, cfg.vocab_size, size=8),
+                              max_new_tokens=args.tokens))
+    done = srv.run_queue()
+    ok = sum(r.metrics.tokens == args.tokens for r in done)
+    repairs = sum(r.metrics.repairs for r in done)
+    failures = sum(r.metrics.failures for r in done)
+    lats = [ms for r in done for ms in r.metrics.token_latency_ms]
+    lat_s = np.mean(lats) / 1e3 if lats else float("nan")
+    print(f"SSR {ok / args.requests:.2f}  {lat_s:.2f}s/token  "
+          f"{repairs} repairs  {failures} failures  "
+          f"{srv.router.stats.windows} windows")
 
-    for algo in ("gtrac", "sp", "mr"):
-        srv = GTRACPipelineServer(cfg, params,
-                                  layers_per_stage=args.layers_per_stage,
-                                  replicas=replicas, algorithm=algo,
-                                  seed=args.seed)
-        ok = repairs = failures = 0
-        lats = []
-        for rid in range(args.requests):
-            prompt = rng.integers(1, cfg.vocab_size, size=8)
-            out, met = srv.generate(prompt, max_new_tokens=args.tokens,
-                                    request_id=rid)
-            ok += met.tokens == args.tokens
-            repairs += met.repairs
-            failures += met.failures
-            lats.extend(met.token_latency_ms)
-        lat_s = np.mean(lats) / 1e3 if lats else float("nan")
-        print(f"{algo:8s} {ok/args.requests:6.2f} {lat_s:9.2f}s "
-              f"{repairs:8d} {failures:9d}")
-
-    print("\nexpected: gtrac matches mr's reliability at the lowest latency;"
-          "\nsp keeps picking honeypots — at this small scale the one-shot"
-          "\nrepair often rescues it, but at ~3x the per-token latency and"
-          "\nan order of magnitude more repairs (the paper-scale SSR gap is"
-          "\nin benchmarks/bench_ssr.py: sp < 0.15 vs gtrac ~= 1.0).")
+    print("\nthe routing-policy comparison (gtrac vs sp / mr / naive / larac,"
+          "\nno stage compute) is benchmarks/bench_ssr.py: at paper scale sp"
+          "\nkeeps picking honeypots, SSR < 0.15 vs gtrac ~= 1.0.")
 
 
 if __name__ == "__main__":

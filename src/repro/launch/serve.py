@@ -1,7 +1,7 @@
 """Serving launcher: plain batched engine or G-TRAC trust-routed pipeline.
 
     PYTHONPATH=src python -m repro.launch.serve --arch gpt2-large --reduced \
-        --mode gtrac --algorithm gtrac --tokens 16
+        --mode gtrac --tokens 16
 """
 from __future__ import annotations
 
@@ -26,21 +26,14 @@ def main(argv=None):
     ap.add_argument("--arch", default="gpt2-large")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--mode", default="gtrac", choices=["engine", "gtrac"])
-    ap.add_argument("--algorithm", default="gtrac",
-                    choices=["gtrac", "sp", "mr", "naive", "larac"])
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--layers-per-stage", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--windowed", action="store_true",
-                    help="gtrac mode: serve all requests concurrently via "
-                         "the window-batched router (one batched DP per "
-                         "token window) instead of per-token routing")
     ap.add_argument("--disaggregate", action="store_true",
-                    help="windowed serving: long prompts prefill in "
-                         "dedicated chunked windows feeding the decode "
-                         "pool instead of stalling it (requires "
-                         "--windowed)")
+                    help="gtrac mode: long prompts prefill in dedicated "
+                         "chunked windows feeding the decode pool "
+                         "instead of stalling it")
     ap.add_argument("--prefill-chunk", type=int, default=None, metavar="T",
                     help="prefill chunk size in tokens, and the prompt-"
                          "length threshold above which a stream gets a "
@@ -60,7 +53,7 @@ def main(argv=None):
                     help="fraction of requests carrying a long prompt "
                          "(0 = all short, the classic workload)")
     ap.add_argument("--burst-every", type=float, default=0.0, metavar="S",
-                    help="windowed serving: arrivals come in bursts "
+                    help="gtrac mode: arrivals come in bursts "
                          "spaced S sim-seconds apart (0 = all queued "
                          "up front)")
     ap.add_argument("--burst-size", type=int, default=4,
@@ -157,21 +150,8 @@ def main(argv=None):
                          "Chrome trace-event file for chrome://tracing "
                          "/ Perfetto (default: jsonl)")
     args = ap.parse_args(argv)
-    if args.windowed and args.algorithm != "gtrac":
-        ap.error("--windowed routes via the gtrac batch router; "
-                 "--algorithm %s is only available per-token" % args.algorithm)
-    if args.hedged and not args.windowed:
-        ap.error("--hedged is a window-serving feature (run_queue); "
-                 "add --windowed — the per-token generate() path does "
-                 "not hedge")
-    if args.algorithm != "gtrac" and args.gossip:
-        ap.error("--gossip serves from the trust-aware seeker cache; "
-                 "--algorithm %s does not consume it" % args.algorithm)
     if args.relay and not args.gossip:
         ap.error("--relay rides on the gossip sync plane; add --gossip")
-    if args.disaggregate and not args.windowed:
-        ap.error("--disaggregate splits the window-batched serving loop "
-                 "(run_queue); add --windowed")
 
     enable_compile_cache()
     cfg = get_config(args.arch)
@@ -228,81 +208,62 @@ def main(argv=None):
                        **gossip_kw)
     srv = GTRACPipelineServer(cfg, params,
                               layers_per_stage=args.layers_per_stage,
-                              algorithm=args.algorithm, seed=args.seed,
-                              gcfg=gcfg)
-    if args.windowed:
-        for spec in serving_workload(
-                rng, args.requests, vocab_size=cfg.vocab_size,
-                short_len=args.prompt_len, long_len=args.long_prompt_len,
-                long_fraction=args.long_fraction,
-                max_new_tokens=args.tokens,
-                burst_every_s=args.burst_every,
-                burst_size=args.burst_size):
-            srv.submit(spec)
-        done = srv.run_queue()
-        ok = 0
-        for r in done:
-            met = r.metrics
-            ok += met.tokens == args.tokens
-            print(f"req {r.request_id}: {met.tokens}/{args.tokens} tokens, "
-                  f"{met.repairs} repairs, {met.failures} failures "
-                  f"-> {r.output}")
-        s = srv.router.stats
-        hedges = sum(r.metrics.hedges_fired for r in done)
-        print(f"SSR: {ok}/{args.requests}  windows: {s.windows}  "
-              f"batched DP calls: {s.device_calls} "
-              f"(vs {s.requests} per-token solves)  "
-              f"anchor shards: {args.shards}  hedges fired: {hedges}")
-        ls = latency_summary(done)
-        chunks = sum(r.metrics.prefill_chunks for r in done)
-        print(f"ttft p50/p99: {ls['ttft_p50_ms']:.0f}/"
-              f"{ls['ttft_p99_ms']:.0f} ms  "
-              f"itl p50/p99: {ls['itl_p50_ms']:.0f}/"
-              f"{ls['itl_p99_ms']:.0f} ms  "
-              f"kv warm-hit rate: {ls['warm_hit_rate']:.2f}  "
-              f"prefill chunks: {chunks} "
-              f"({'disaggregated' if args.disaggregate else 'inline'})")
-        print(f"completion: {ls['completed']}/{ls['requests']} requests "
-              f"emitted ({ls['incomplete']} incomplete, rate "
-              f"{ls['completion_rate']:.2f})")
-        if srv.gossip is not None:
-            g = srv.gossip.stats
-            stale = max((r.metrics.stale_rounds_max for r in done),
-                        default=0)
-            print(f"gossip: {g.rounds} rounds, {g.deltas} deltas "
-                  f"({g.delta_bytes} B), {g.full_syncs} full syncs "
-                  f"({g.full_bytes} B), max staleness {stale} rounds")
-            if srv.gossip.relay is not None:
-                rs = srv.gossip.relay.stats
-                print(f"relay: {args.relay_seekers} seekers, "
-                      f"{rs.msgs} msgs ({rs.msg_bytes} B), "
-                      f"{rs.summaries} summaries ({rs.summary_bytes} B), "
-                      f"{rs.deltas_applied} deltas applied, "
-                      f"{rs.duplicates} duplicates, "
-                      f"{rs.gaps} gaps ({rs.anchor_repairs} anchor / "
-                      f"{rs.peer_full_syncs} peer repairs), "
-                      f"anchor bytes {g.anchor_bytes()} B")
-                print(f"relay hardening: {rs.digest_mismatches} digest "
-                      f"mismatches, {rs.rejected_chains} rejected "
-                      f"chains, {rs.quarantines} quarantines "
-                      f"({rs.quarantine_drops} drops), "
-                      f"{rs.hb_rejected} hb rejections")
-        _report_control_plane(srv)
-        _dump_trace(srv, args)
-        srv.close()
-        return
+                              seed=args.seed, gcfg=gcfg)
+    for spec in serving_workload(
+            rng, args.requests, vocab_size=cfg.vocab_size,
+            short_len=args.prompt_len, long_len=args.long_prompt_len,
+            long_fraction=args.long_fraction,
+            max_new_tokens=args.tokens,
+            burst_every_s=args.burst_every,
+            burst_size=args.burst_size):
+        srv.submit(spec)
+    done = srv.run_queue()
     ok = 0
-    for rid in range(args.requests):
-        prompt = rng.integers(1, cfg.vocab_size, size=args.prompt_len)
-        out, met = srv.generate(prompt, max_new_tokens=args.tokens,
-                                request_id=rid)
+    for r in done:
+        met = r.metrics
         ok += met.tokens == args.tokens
-        lat = (np.mean(met.token_latency_ms) / 1e3
-               if met.token_latency_ms else float("nan"))
-        print(f"req {rid}: {met.tokens}/{args.tokens} tokens, "
-              f"{met.repairs} repairs, {met.failures} failures, "
-              f"{lat:.2f}s/token -> {list(out)}")
-    print(f"SSR: {ok}/{args.requests}")
+        print(f"req {r.request_id}: {met.tokens}/{args.tokens} tokens, "
+              f"{met.repairs} repairs, {met.failures} failures "
+              f"-> {r.output}")
+    s = srv.router.stats
+    hedges = sum(r.metrics.hedges_fired for r in done)
+    print(f"SSR: {ok}/{args.requests}  windows: {s.windows}  "
+          f"batched DP calls: {s.device_calls} "
+          f"(vs {s.requests} per-token solves)  "
+          f"anchor shards: {args.shards}  hedges fired: {hedges}")
+    ls = latency_summary(done)
+    chunks = sum(r.metrics.prefill_chunks for r in done)
+    print(f"ttft p50/p99: {ls['ttft_p50_ms']:.0f}/"
+          f"{ls['ttft_p99_ms']:.0f} ms  "
+          f"itl p50/p99: {ls['itl_p50_ms']:.0f}/"
+          f"{ls['itl_p99_ms']:.0f} ms  "
+          f"kv warm-hit rate: {ls['warm_hit_rate']:.2f}  "
+          f"prefill chunks: {chunks} "
+          f"({'disaggregated' if args.disaggregate else 'inline'})")
+    print(f"completion: {ls['completed']}/{ls['requests']} requests "
+          f"emitted ({ls['incomplete']} incomplete, rate "
+          f"{ls['completion_rate']:.2f})")
+    if srv.gossip is not None:
+        g = srv.gossip.stats
+        stale = max((r.metrics.stale_rounds_max for r in done), default=0)
+        print(f"gossip: {g.rounds} rounds, {g.deltas} deltas "
+              f"({g.delta_bytes} B), {g.full_syncs} full syncs "
+              f"({g.full_bytes} B), max staleness {stale} rounds")
+        if srv.gossip.relay is not None:
+            rs = srv.gossip.relay.stats
+            print(f"relay: {args.relay_seekers} seekers, "
+                  f"{rs.msgs} msgs ({rs.msg_bytes} B), "
+                  f"{rs.summaries} summaries ({rs.summary_bytes} B), "
+                  f"{rs.deltas_applied} deltas applied, "
+                  f"{rs.duplicates} duplicates, "
+                  f"{rs.gaps} gaps ({rs.anchor_repairs} anchor / "
+                  f"{rs.peer_full_syncs} peer repairs), "
+                  f"anchor bytes {g.anchor_bytes()} B")
+            print(f"relay hardening: {rs.digest_mismatches} digest "
+                  f"mismatches, {rs.rejected_chains} rejected "
+                  f"chains, {rs.quarantines} quarantines "
+                  f"({rs.quarantine_drops} drops), "
+                  f"{rs.hb_rejected} hb rejections")
     _report_control_plane(srv)
     _dump_trace(srv, args)
     srv.close()

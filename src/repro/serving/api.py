@@ -1,13 +1,9 @@
 """Unified serving submission API.
 
-Three submission surfaces drifted apart across PRs 1-7:
-``ServingEngine.submit(prompt, ...)``, ``GTRACPipelineServer.submit(prompt,
-tau=..., ...)``, and hand-built ``engine.Request`` objects pushed straight
-into an ``AdmissionQueue``. ``SubmitSpec`` is the one canonical surface:
-both engines accept it directly (``engine.submit(SubmitSpec(...))``), the
-old keyword forms survive as thin shims that forward here and emit
-``DeprecationWarning``, and request ids are allocated by the admission
-queue's monotonic counter unless the caller pins one explicitly.
+``SubmitSpec`` is the one submission surface: the ``submit`` of both
+engines (``ServingEngine``, ``GTRACPipelineServer``) takes it and nothing
+else, and request ids are allocated by the admission queue's monotonic
+counter unless the caller pins one explicitly.
 
 Stream kinds
 ------------

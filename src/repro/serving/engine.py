@@ -4,13 +4,11 @@ the trust-routed pipeline server in gtrac_serve.py layers G-TRAC on top
 and shares ``AdmissionQueue`` for its window-batched routing loop).
 
 Submission goes through the unified ``SubmitSpec`` surface
-(serving/api.py); the legacy ``submit(prompt, ...)`` keyword form is a
-deprecated shim. Request ids come from the admission queue's monotonic
+(serving/api.py). Request ids come from the admission queue's monotonic
 counter, never from queue-state arithmetic.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -47,13 +45,6 @@ class Request:
                    max_new_tokens=int(spec.max_new_tokens),
                    eos_id=spec.eos_id, tau=spec.tau,
                    arrival_time=float(spec.arrival_time), kind=spec.kind)
-
-
-def _deprecated_submit(owner: str) -> None:
-    warnings.warn(
-        f"{owner}.submit(prompt, ...) keyword form is deprecated; "
-        f"pass a repro.serving.api.SubmitSpec instead",
-        DeprecationWarning, stacklevel=3)
 
 
 class AdmissionQueue:
@@ -185,17 +176,8 @@ class ServingEngine:
     def queue(self) -> List[Request]:
         return self.admission.pending
 
-    def submit(self, spec, max_new_tokens: Optional[int] = None,
-               eos_id: Optional[int] = None) -> Request:
-        """Queue one stream. ``spec`` is a ``SubmitSpec`` (the canonical
-        surface); passing a raw prompt array with keywords is the
-        deprecated PR-2-era form and forwards through a shim."""
-        if not isinstance(spec, SubmitSpec):
-            _deprecated_submit("ServingEngine")
-            spec = SubmitSpec(prompt=spec,
-                              max_new_tokens=(16 if max_new_tokens is None
-                                              else max_new_tokens),
-                              eos_id=eos_id)
+    def submit(self, spec: SubmitSpec) -> Request:
+        """Queue one stream."""
         rid = (self.admission.next_request_id()
                if spec.request_id is None else spec.request_id)
         return self.admission.submit(Request.from_spec(spec, rid))

@@ -3,27 +3,30 @@ with REAL model compute.
 
 The served model is split into contiguous layer stages (StagePartition).
 Each *peer* is a stage replica with its own latency/reliability profile
-(sim/peers.py); the Anchor tracks trust; the Seeker routes each token's
-chain from its cached view (G-TRAC / any baseline), and the ChainExecutor
-runs the hops — each hop executes the stage's actual jitted forward on the
-hidden states, exactly the paper's layer-sharded activation relay. Hop
-payloads are stateless (full-prefix recompute per token), matching the
-paper's testbed semantics and making Bounded One-Shot Repair trivially
-correct: a replacement peer needs no KV-state transfer.
+(sim/peers.py); the Anchor tracks trust; the Seeker's cached view feeds
+one batched route solve per token window (serving/batch_router), and each
+stream's ChainExecutor runs the hops — each hop executes the stage's
+actual jitted forward on the hidden states, exactly the paper's
+layer-sharded activation relay. Hop payloads are stateless (full-prefix
+recompute per token), matching the paper's testbed semantics and making
+Bounded One-Shot Repair trivially correct: a replacement peer needs no
+KV-state transfer.
 
-Peers do, however, retain per-stream KV for their own stage, so the
-window-batched loop (``run_queue``) prices every hop by the tokens it must
-*freshly* process: a hop routed back to a warm peer pays for the increment,
-a cold hop recomputes the prefix (serving/kv_cache.KVLocalityTracker).
-``cfg.kv_reuse_bonus`` folds that locality into routing as a per-request
-edge-cost discount — the batched K-best DP prefers, never requires, the
-warm chain. ``cfg.disaggregate`` splits admission windows by prompt length
-(AdmissionQueue.split_by_kind): long prompts prefill in dedicated chunked
-windows (``cfg.prefill_chunk_tokens`` per chunk, at most the decode token
-budget per window) that run asynchronously against the decode cadence and
-hand their warm streams to the continuous decode pool.
+Peers do, however, retain per-stream KV for their own stage, so every hop
+is priced by the tokens it must *freshly* process: a hop routed back to a
+warm peer pays for the increment, a cold hop recomputes the prefix
+(serving/kv_cache.KVLocalityTracker). ``cfg.kv_reuse_bonus`` folds that
+locality into routing as a per-request edge-cost discount — the batched
+K-best DP prefers, never requires, the warm chain. ``cfg.disaggregate``
+splits admission windows by prompt length (AdmissionQueue.split_by_kind):
+long prompts prefill in dedicated chunked windows
+(``cfg.prefill_chunk_tokens`` per chunk, at most the decode token budget
+per window) that run asynchronously against the decode cadence and hand
+their warm streams to the continuous decode pool.
 
-This powers examples/serve_gtrac.py and the integration tests.
+``run_queue`` is the one serving loop; ``generate`` serves one stream
+through it. This powers examples/serve_gtrac.py, repro.launch.serve and
+the chip benchmark.
 """
 from __future__ import annotations
 
@@ -41,9 +44,8 @@ import numpy as np
 from repro.configs.base import GTRACConfig, ModelConfig
 from repro.core.executor import ChainExecutor, split_reports
 from repro.core.hedging import HedgedChainExecutor
-from repro.core.planner import RoutePlanner, plan_route
+from repro.core.planner import RoutePlanner
 from repro.core.registry import SeekerCache
-from repro.core.routing import ALGORITHMS
 from repro.core.sharding import make_registry
 from repro.core.types import HopReport
 from repro.distributed.pipeline import StagePartition
@@ -58,7 +60,7 @@ from repro.obs.trace import (
 )
 from repro.serving.api import SubmitSpec
 from repro.serving.batch_router import BatchRouter
-from repro.serving.engine import AdmissionQueue, Request, _deprecated_submit
+from repro.serving.engine import AdmissionQueue, Request
 from repro.serving.kv_cache import KVLocalityTracker
 from repro.sim.peers import PROFILES, SimPeer, make_peer
 from repro.sim.testbed import Testbed
@@ -137,10 +139,11 @@ def make_stage_fns(cfg: ModelConfig, params, partition: StagePartition,
     weights of ``served_stage_params``, made once, here. Where the family
     has a ``stage_bucket``, each hop right-pads ``tokens`` to a multiple
     of it, so ``x`` between stages holds the padded positions; the
-    payload's ``tokens`` stay as they came. ``run_queue`` gives them as
-    host ids, so the pad reads nothing from the device. ``obs`` gets
-    the weights' bytes per dtype as gauges ``stage/weight_bytes_<dtype>``,
-    and the shared blocks' as ``stage/shared_block_bytes``."""
+    payload's ``tokens`` stay as they came. Every hop gets them as host
+    ids (a prompt slice or ``RoutedRequest.ids()``), so the pad reads
+    nothing from the device. ``obs`` gets the weights' bytes per dtype
+    as gauges ``stage/weight_bytes_<dtype>``, and the shared blocks' as
+    ``stage/shared_block_bytes``."""
     stages = served_stage_params(cfg, params, partition)
     if obs is not None:
         for name, n in weight_bytes(stages).items():
@@ -209,21 +212,6 @@ def hook_process_spans(tracer) -> weakref.finalize:
     gc.callbacks.append(on_gc)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     return weakref.finalize(tracer, unhook)
-
-
-def sample_token(logits, rng: np.random.Generator,
-                 temperature: float = 1.0) -> int:
-    """Temperature sampling off the testbed RNG: softmax of the last
-    position's logits at ``temperature``, one categorical draw. Runs on
-    host numpy — the testbed's RNG is the single source of randomness
-    for the whole sim (failures, latencies, sampling), which keeps runs
-    reproducible per seed."""
-    z = np.asarray(logits, np.float64).reshape(-1)
-    z = z / max(float(temperature), 1e-6)
-    z -= z.max()
-    p = np.exp(z)
-    p /= p.sum()
-    return int(rng.choice(len(p), p=p))
 
 
 # ---------------------------------------------------------------------------
@@ -355,19 +343,17 @@ class RoutedRequest(Request):
 
 
 class GTRACPipelineServer:
-    """Serve a model across simulated stage-replica peers under a routing
-    policy. Peers execute REAL stage compute; failures/latency are injected
+    """Serve a model across simulated stage-replica peers, routed by
+    G-TRAC. Peers execute REAL stage compute; failures/latency are injected
     per their profile; trust state evolves exactly as in the paper."""
 
     def __init__(self, cfg: ModelConfig, params,
                  layers_per_stage: int,
                  replicas: Dict[str, int] = None,
                  gcfg: Optional[GTRACConfig] = None,
-                 algorithm: str = "gtrac",
                  seed: int = 0):
         self.cfg = cfg
         self.gcfg = gcfg or GTRACConfig()
-        self.algorithm = algorithm
         self.partition = StagePartition.uniform(cfg.num_layers,
                                                 layers_per_stage)
         # the telemetry plane (below) starts with the stage weights'
@@ -419,8 +405,8 @@ class GTRACPipelineServer:
                 anchor, self.gcfg,
                 n_seekers=max(1, self.gcfg.gossip_seekers), now=0.0)
             self.sync_seeker = sync_seekers[0]
-        # per-server planner: compiled CSR graph + K-best plans are reused
-        # across every token routed from an unchanged registry snapshot
+        # per-server planner: the compiled CSR graph is reused across
+        # every window routed from an unchanged registry snapshot
         self.planner = RoutePlanner(cfg.num_layers,
                                     k_best=self.gcfg.k_best_routes,
                                     cache_size=self.gcfg.planner_cache_size)
@@ -430,7 +416,8 @@ class GTRACPipelineServer:
                                   total_layers=cfg.num_layers)
         # admission owns the per-window registry sweep (per-shard fan-out
         # when the anchor is sharded) AND the request-id space: ids come
-        # from its monotonic counter, seeded clear of generate()'s
+        # from its monotonic counter, seeded above the small ids callers
+        # pin (``generate(request_id=...)``) so the two never collide
         self.admission = AdmissionQueue(max_batch=self.gcfg.router_max_batch,
                                         registry=anchor, id_base=10_000)
         # which peers hold which stream's warm KV — prices hops by freshly
@@ -502,12 +489,11 @@ class GTRACPipelineServer:
 
     # -- hop adapter -----------------------------------------------------------
 
-    def _hop_fn(self, request_id: int, kv_tracked: bool = False):
-        """Hop closure for one stream. With ``kv_tracked`` (the window
-        loop) a hop is charged for the tokens it freshly processes —
-        prefix length minus the peer's warm KV position — so warm chains
-        decode at incremental cost while cold hops recompute. The default
-        keeps ``generate``'s classic flat per-token charge."""
+    def _hop_fn(self, request_id: int):
+        """Hop closure for one stream. A hop is charged for the tokens it
+        freshly processes — prefix length minus the peer's warm KV
+        position — so warm chains decode at incremental cost while cold
+        hops and prefill chunks recompute."""
         def hop(peer_id: int, k: int, payload):
             peer = self.bed.peers[peer_id]
             if not self.bed.reachable(peer_id) or \
@@ -519,22 +505,20 @@ class GTRACPipelineServer:
             with (ht.span("hop.dispatch", stage=stage) if ht.enabled
                   else NOOP_SPAN):
                 out = self.stage_fns[stage](payload)   # REAL compute
-            ntok = 1
-            if kv_tracked:
-                prefix = int(payload[0].shape[1])
-                ntok = max(1, prefix - self.kv.warm_pos(request_id, peer_id))
-                if ntok > 1:
-                    # latency_est_ms means ONE decode step everywhere
-                    # (routing costs, hedge triggers) — remember how to
-                    # rescale this multi-token observation back to its
-                    # single-token equivalent before the anchor EMA sees
-                    # it, or a prefill chunk / cold recompute makes the
-                    # charged peer look slow and routing ping-pongs
-                    # between replicas, each flip paying a full-prefix
-                    # recompute.
-                    one = peer.compute_ms(1) + peer.net_delay_ms
-                    full = peer.compute_ms(ntok) + peer.net_delay_ms
-                    self._tok_scale[(request_id, peer_id)] = one / full
+            prefix = int(payload[0].shape[1])
+            ntok = max(1, prefix - self.kv.warm_pos(request_id, peer_id))
+            if ntok > 1:
+                # latency_est_ms means ONE decode step everywhere
+                # (routing costs, hedge triggers) — remember how to
+                # rescale this multi-token observation back to its
+                # single-token equivalent before the anchor EMA sees
+                # it, or a prefill chunk / cold recompute makes the
+                # charged peer look slow and routing ping-pongs
+                # between replicas, each flip paying a full-prefix
+                # recompute.
+                one = peer.compute_ms(1) + peer.net_delay_ms
+                full = peer.compute_ms(ntok) + peer.net_delay_ms
+                self._tok_scale[(request_id, peer_id)] = one / full
             return out, peer.hop_latency_ms(self.bed.rng, tokens=ntok), True
 
         return hop
@@ -562,77 +546,18 @@ class GTRACPipelineServer:
     # -- serving ---------------------------------------------------------------
 
     def generate(self, prompt: np.ndarray, max_new_tokens: int,
-                 request_id: int = 0, greedy: bool = True,
-                 temperature: float = 1.0)\
+                 request_id: Optional[int] = None)\
             -> Tuple[np.ndarray, ServeMetrics]:
-        tokens = jnp.asarray(prompt, jnp.int32)[None, :]
-        metrics = ServeMetrics()
-        t_start = self.bed.now
-        route_fn = ALGORITHMS[self.algorithm]
-        executor = ChainExecutor(self.gcfg, self._hop_fn(request_id))
-        tr = self.tracer
-        traced = tr.enabled
-        rsp = None
-        if traced:
-            executor.tracer = tr
-            rsp = tr.begin("request", cat="request", t0=t_start,
-                           rid=request_id)
-
-        for _ in range(max_new_tokens):
-            table = self._sync_and_view()
-            plan = None
-            if self.algorithm == "gtrac":
-                # planner path: K-best plan cached per snapshot version
-                route, plan = plan_route(table, self.cfg.num_layers,
-                                         self.gcfg, planner=self.planner)
-            else:
-                kwargs = ({"rng": self.bed.rng}
-                          if self.algorithm == "naive" else {})
-                route = route_fn(table, self.cfg.num_layers, self.gcfg,
-                                 **kwargs)
-            if not route.feasible:
-                metrics.infeasible += 1
-                break
-            t_tok = self.bed.now
-            report, payload = executor.execute(route.chain, table,
-                                               payload=(tokens, None),
-                                               plan=plan)
-            for rep in split_reports(report):
-                self.bed.anchor.apply_report(rep)
-            metrics.repairs += int(report.repaired)
-            metrics.rerouted += int(report.repaired)
-            self.bed.advance(report.total_latency_ms / 1e3)
-            if traced:
-                ssp = tr.add("decode.step", t_tok, self.bed.now,
-                             cat="decode", parent=rsp, rid=request_id,
-                             emitted=report.success,
-                             first_token=(report.success
-                                          and metrics.ttft_ms < 0))
-                self._trace_hops(ssp, t_tok, report)
-            if not report.success:
-                metrics.failures += 1
-                break
-            _, logits = payload
-            if greedy:
-                nxt = jnp.argmax(logits[:, -1, :], -1)
-            else:
-                tok = sample_token(logits[:, -1, :], self.bed.rng,
-                                   temperature)
-                nxt = jnp.full((tokens.shape[0],), tok, jnp.int32)
-            tokens = jnp.concatenate([tokens, nxt[:, None].astype(jnp.int32)],
-                                     axis=1)
-            metrics.tokens += 1
-            metrics.token_latency_ms.append(report.total_latency_ms)
-            metrics.emit_ms.append(self.bed.now * 1e3)
-            if metrics.ttft_ms < 0:
-                metrics.ttft_ms = (self.bed.now - t_start) * 1e3
-        self.bed.peers and [p.forget_request(request_id)
-                            for p in self.bed.peers.values()]
-        if traced:
-            tr.end(rsp, t1=self.bed.now, ttft_ms=metrics.ttft_ms,
-                   stale_rounds_max=metrics.stale_rounds_max)
-        self._fill_stream_metrics(metrics)
-        return np.asarray(tokens[0, len(prompt):]), metrics
+        """Serve one stream, arriving now, through ``run_queue`` and
+        return its tokens and metrics: a window of one, unless streams
+        were already queued, which are served beside it. ``request_id``
+        pins the stream's id."""
+        req = self.submit(SubmitSpec(prompt=prompt,
+                                     max_new_tokens=max_new_tokens,
+                                     arrival_time=self.bed.now,
+                                     request_id=request_id))
+        self.run_queue()
+        return np.asarray(req.output, np.int32), req.metrics
 
     def _trace_hops(self, parent, t0: float, report) -> None:
         """Synthesize per-hop child spans under an exec span from the
@@ -665,26 +590,17 @@ class GTRACPipelineServer:
 
     # -- window-batched serving (the batch router path) ------------------------
 
-    def submit(self, spec, max_new_tokens: int = 16,
-               tau: Optional[float] = None,
-               request_id: Optional[int] = None) -> RoutedRequest:
+    def submit(self, spec: SubmitSpec) -> RoutedRequest:
         """Queue a stream for window-batched serving.
 
-        ``spec`` is a ``repro.serving.api.SubmitSpec`` — the unified
-        submission surface; its ``tau`` is this request's trust floor
-        (row of the batched DP's tau vector, None = configured floor),
-        ``arrival_time`` defers admission, ``kind`` pins the stream to
-        the prefill/decode split under ``cfg.disaggregate``. Passing a
-        raw prompt array with keywords is the deprecated pre-SubmitSpec
-        form and forwards through a shim."""
-        if not isinstance(spec, SubmitSpec):
-            _deprecated_submit("GTRACPipelineServer")
-            spec = SubmitSpec(prompt=spec, max_new_tokens=max_new_tokens,
-                              tau=tau, request_id=request_id)
+        ``spec.tau`` is this request's trust floor (row of the batched
+        DP's tau vector, None = configured floor), ``arrival_time``
+        defers admission, ``kind`` pins the stream to the prefill/decode
+        split under ``cfg.disaggregate``."""
         rid = (self.admission.next_request_id()
                if spec.request_id is None else spec.request_id)
         req = RoutedRequest.from_spec(spec, rid)
-        hop = self._hop_fn(rid, kv_tracked=True)
+        hop = self._hop_fn(rid)
         # hedged window serving: behind cfg.hedge_enabled each stream runs
         # the hedging executor (fires a backup hop when the primary exceeds
         # hedge_quantile_factor x its latency estimate); plans splice

@@ -540,8 +540,9 @@ class TestTracedServing:
         assert srv.router.tracer is NOOP_TRACER
 
     def test_generate_path_traced(self, tiny_model):
-        """The per-token generate() loop also carries request/step/hop
-        spans, and the first step IS the TTFT (no queue, no windows)."""
+        """generate() is run_queue's window of one: one request span,
+        one window and one decode step per token, hops that tile their
+        step, no queue wait, and the first step IS the TTFT."""
         srv = _traced_server(tiny_model)
         out, met = srv.generate(np.arange(1, 9), max_new_tokens=3,
                                 request_id=77)
@@ -551,8 +552,14 @@ class TestTracedServing:
         assert row["ttft_sum_ms"] == pytest.approx(
             row["measured_ttft_ms"], abs=1e-6)
         assert row["measured_ttft_ms"] == pytest.approx(met.ttft_ms)
+        names = [s.name for s in srv.trace.spans]
+        assert names.count("request") == 1
+        assert names.count("serve.window") == 3
+        assert "queue.wait" not in names
+        (req,) = [s for s in srv.trace.spans if s.name == "request"]
         steps = [s for s in srv.trace.spans if s.name == "decode.step"]
         assert len(steps) == 3
+        assert all(st.parent_id == req.span_id for st in steps)
         hops = [s for s in srv.trace.spans if s.name == "hop"]
         by_id = {s.span_id: s for s in srv.trace.spans}
         for h in hops:                       # hops tile their step

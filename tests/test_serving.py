@@ -18,7 +18,6 @@ from repro.serving.engine import ServingEngine
 from repro.serving.gtrac_serve import (
     GTRACPipelineServer,
     make_stage_fns,
-    sample_token,
     served_stage_params,
     stage_step,
 )
@@ -73,8 +72,7 @@ class TestGTRACServer:
         computations must reproduce monolithic greedy decoding exactly."""
         cfg, model, params = tiny
         srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
-                                  replicas={"golden": 2}, algorithm="gtrac",
-                                  seed=0)
+                                  replicas={"golden": 2}, seed=0)
         prompt = np.arange(1, 9)
         out, met = srv.generate(prompt, max_new_tokens=5)
         want = monolithic_greedy(cfg, model, params, prompt, 5)
@@ -170,70 +168,13 @@ class TestGTRACServer:
         cfg, model, params = tiny
         srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
                                   replicas={"honeypot": 2, "golden": 2},
-                                  algorithm="gtrac", seed=1)
+                                  seed=1)
         done = 0
         for rid in range(6):
             out, met = srv.generate(np.arange(1, 9), max_new_tokens=4,
                                     request_id=rid)
             done += met.tokens == 4
         assert done >= 4  # converges to golden peers after early strikes
-
-    def test_sp_baseline_worse_than_gtrac(self, tiny):
-        cfg, model, params = tiny
-
-        def run(algo, seed):
-            srv = GTRACPipelineServer(
-                cfg, params, layers_per_stage=2,
-                replicas={"honeypot": 3, "golden": 1, "turtle": 1},
-                algorithm=algo, seed=seed)
-            ok = 0
-            for rid in range(8):
-                _, met = srv.generate(np.arange(1, 9), max_new_tokens=3,
-                                      request_id=rid)
-                ok += met.tokens == 3
-            return ok / 8
-
-        g = np.mean([run("gtrac", s) for s in range(2)])
-        s = np.mean([run("sp", s) for s in range(2)])
-        assert g >= s  # the honey-pot effect (paper §VI-A)
-
-    def test_nongreedy_sampling_can_emit_non_argmax(self, tiny):
-        """Regression: generate(greedy=False) was dead code — both
-        branches of the conditional took argmax. Real temperature
-        sampling off the testbed RNG must be able to leave the argmax
-        chain (same params + prompt, so any divergence is sampling)."""
-        cfg, model, params = tiny
-
-        def build():
-            return GTRACPipelineServer(cfg, params, layers_per_stage=2,
-                                       replicas={"golden": 2},
-                                       algorithm="gtrac", seed=0)
-
-        prompt = np.arange(1, 9)
-        greedy_out, gm = build().generate(prompt, max_new_tokens=6,
-                                          greedy=True)
-        sampled, sm = build().generate(prompt, max_new_tokens=6,
-                                       greedy=False, temperature=8.0)
-        assert gm.tokens == 6 and sm.tokens == 6
-        assert all(0 <= t < cfg.vocab_size for t in sampled)
-        assert list(sampled) != list(greedy_out)   # pre-fix: identical
-
-    def test_sample_token_temperature_law(self):
-        """Low temperature concentrates on the argmax; high temperature
-        spreads — and every draw comes off the supplied RNG."""
-        logits = np.zeros(32)
-        logits[7] = 4.0
-        cold = {sample_token(logits, np.random.default_rng(0), 0.05)
-                for _ in range(50)}
-        assert cold == {7}
-        rng = np.random.default_rng(0)
-        hot = [sample_token(logits, rng, 4.0) for _ in range(300)]
-        assert 7 in hot
-        assert any(t != 7 for t in hot)
-        # determinism per seed: the testbed RNG is the only entropy
-        rng2 = np.random.default_rng(0)
-        assert hot == [sample_token(logits, rng2, 4.0)
-                       for _ in range(300)]
 
     def test_windowed_serving_with_relay_plane(self, tiny):
         """run_queue serves correctly off a relay-enabled gossip seeker
@@ -260,7 +201,7 @@ class TestGTRACServer:
         cfg, model, params = tiny
         srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
                                   replicas={"honeypot": 2, "golden": 2},
-                                  algorithm="gtrac", seed=5)
+                                  seed=5)
         want = monolithic_greedy(cfg, model, params, np.arange(1, 9), 4)
         for rid in range(8):
             out, met = srv.generate(np.arange(1, 9), max_new_tokens=4,
@@ -360,6 +301,33 @@ class TestOneTokenReadPerWindow:
             assert req.metrics.token_latency_ms == pytest.approx(
                 latency_ms, rel=1e-12)
 
+    def test_generate_is_a_window_of_one(self, family):
+        """``generate`` is ``submit`` + ``run_queue`` of one stream
+        arriving now: on twin servers, stream after stream, both give
+        the same ids, tokens and sim-clock stamps to the bit."""
+        cfg, params, prompts, budgets = _window_model(family)
+
+        def fleet():
+            return GTRACPipelineServer(cfg, params, layers_per_stage=2,
+                                       replicas={"honeypot": 1, "turtle": 1,
+                                                 "golden": 2}, seed=3)
+
+        gen, win = fleet(), fleet()
+        for p, n in zip(prompts, budgets):
+            out, met = gen.generate(p, max_new_tokens=n)
+            req = win.submit(SubmitSpec(prompt=p, max_new_tokens=n,
+                                        arrival_time=win.bed.now))
+            assert win.run_queue() == [req]
+            assert out.tolist() == req.output
+            assert met.emit_ms == req.metrics.emit_ms
+            assert met.token_latency_ms == req.metrics.token_latency_ms
+            assert met.ttft_ms == req.metrics.ttft_ms
+            assert (met.tokens, met.repairs, met.failures) == (
+                req.metrics.tokens, req.metrics.repairs,
+                req.metrics.failures)
+        assert gen.bed.now == win.bed.now
+        assert gen.admission._next_id == win.admission._next_id
+
     def test_one_read_per_window_after_its_last_execute(self, family,
                                                         monkeypatch):
         """Token ids cross to the host once per decode window, after every
@@ -453,21 +421,6 @@ class TestSubmitSpecAPI:
             SubmitSpec(prompt=np.arange(4), max_new_tokens=0)
         spec = SubmitSpec(prompt=[1, 2, 3])
         assert spec.prompt.dtype == np.int32 and spec.kind == "auto"
-
-    def test_engine_shim_warns_and_behaves(self, tiny):
-        cfg, model, params = tiny
-        eng = ServingEngine(cfg, params)
-        with pytest.deprecated_call():
-            req = eng.submit(np.arange(1, 5), max_new_tokens=2)
-        assert req.max_new_tokens == 2 and req.request_id == 0
-
-    def test_server_shim_warns(self, tiny):
-        cfg, model, params = tiny
-        srv = GTRACPipelineServer(cfg, params, layers_per_stage=2,
-                                  replicas={"golden": 2}, seed=0)
-        with pytest.deprecated_call():
-            req = srv.submit(np.arange(1, 5), max_new_tokens=2)
-        assert req.request_id == 10_000
 
     def test_pinned_request_id_advances_counter(self, tiny):
         cfg, model, params = tiny
